@@ -80,14 +80,6 @@ def _synthetic_graph(n_preds: int, rows_per_pred: int = 2048,
     return np.unique(tt, axis=0)
 
 
-def _builds_identical(a, b) -> bool:
-    return (a.sf == b.sf and a.sizes == b.sizes
-            and set(a.tables) == set(b.tables)
-            and all(np.array_equal(a.tables[k].rows, b.tables[k].rows)
-                    for k in a.tables)
-            and a.n_semijoins == b.n_semijoins)
-
-
 def bench_extvp(pred_counts: Sequence[int] = (8, 32, 64),
                 watdiv_scale: Optional[float] = 0.1,
                 threshold: float = 0.25, repeats: int = 3,
@@ -130,7 +122,7 @@ def bench_extvp(pred_counts: Sequence[int] = (8, 32, 64),
             "numpy_semijoins_per_s": base.n_semijoins / max(numpy_s, 1e-9),
             "jax_semijoins_per_s": base.n_semijoins / max(jax_s, 1e-9),
             "speedup": numpy_s / max(jax_s, 1e-9),
-            "identical": _builds_identical(base, dev),
+            "identical": base.identical(dev),
         })
 
     report = {"pair_batch": pair_batch, "repeats": repeats, "cases": cases}

@@ -17,6 +17,7 @@ import time
 import numpy as np
 
 from repro import Dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.rdf.workloads import ST_QUERIES, basic_queries, il_queries
 
 
@@ -29,6 +30,7 @@ def main() -> None:
                     help="ExecutionBackend registry key (eager/jit/...) or "
                          "'auto' for per-template adaptive routing")
     args = ap.parse_args()
+    enable_compile_cache()
 
     print(f"generating WatDiv SF={args.scale} ...")
     t0 = time.perf_counter()

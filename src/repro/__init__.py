@@ -12,21 +12,6 @@ importable directly; heavyweight submodules (models, kernels, launch) are
 not imported here.
 """
 
-import os as _os
-
-# The XLA CPU "thunk" runtime shipped around jaxlib 0.4.3x miscompiles
-# sort→gather chains in the relational programs (a row gather through a
-# lexsort permutation returns PAD rows downstream of a cross join;
-# verified: results are correct under --xla_cpu_use_thunk_runtime=false
-# or --xla_backend_optimization_level=0, wrong otherwise).  Pin the
-# legacy CPU runtime before the first backend initialization.  Appending
-# respects any user-provided XLA_FLAGS; device backends other than CPU
-# ignore this flag.
-_flags = _os.environ.get("XLA_FLAGS", "")
-if "xla_cpu_use_thunk_runtime" not in _flags:
-    _os.environ["XLA_FLAGS"] = \
-        (_flags + " --xla_cpu_use_thunk_runtime=false").strip()
-
 from repro.engine import (
     ConstantBinding, Dataset, Engine, ExecutionBackend, ExecutionContext,
     PreparedQuery, QueryTemplate, Result, RuntimeConfig, ServerMetrics,

@@ -4,7 +4,7 @@ A static pass over Python source that flags the pitfalls which only
 surface at trace/serving time in this codebase: Python control flow on
 traced values, host synchronisations inside device code, int literals
 past the int32 lattice, shapes derived from traced counts, and
-``shard_map`` calls that never took an explicit ``check_rep`` decision.
+``shard_map`` calls that never took an explicit ``check_vma`` decision.
 
 The lint is *scoped*: most rules only apply inside functions the
 analyzer believes are traced.  A function is traced when any of
@@ -74,8 +74,8 @@ RULES: Dict[str, str] = {
         "Array constructor whose shape derives from a traced value "
         "(e.g. a JBindings .n) — shapes must be static under jit; this "
         "retraces per distinct value or fails outright.",
-    "shard-map-check-rep":
-        "shard_map call without an explicit check_rep decision — "
+    "shard-map-check-vma":
+        "shard_map call without an explicit check_vma decision — "
         "replication checking must be chosen deliberately (and the "
         "choice justified) at every call site.",
     "bare-suppression":
@@ -160,7 +160,7 @@ def _is_tracer_decorator(dec: ast.expr) -> bool:
 
 class _ModuleScan(ast.NodeVisitor):
     """First pass: function registry, traced seeds, call graph, and the
-    module-wide ``shard-map-check-rep`` check."""
+    module-wide ``shard-map-check-vma`` check."""
 
     def __init__(self, path: str) -> None:
         self.path = path
@@ -197,11 +197,11 @@ class _ModuleScan(ast.NodeVisitor):
                 if at:
                     self.seeds.add(at)
         if term is not None and term.endswith("shard_map"):
-            if not any(kw.arg == "check_rep" for kw in node.keywords):
+            if not any(kw.arg == "check_vma" for kw in node.keywords):
                 self.findings.append(LintFinding(
                     self.path, node.lineno, node.col_offset,
-                    "shard-map-check-rep",
-                    "shard_map call without an explicit check_rep= decision"))
+                    "shard-map-check-vma",
+                    "shard_map call without an explicit check_vma= decision"))
         self.generic_visit(node)
 
     def traced(self) -> Set[str]:

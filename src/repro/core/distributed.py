@@ -36,21 +36,17 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:                                    # jax >= 0.5 exports it at top level
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.core.algebra import is_var
 from repro.core.compiler import (
     BGPSeg, CombineSeg, CorePlan, CoreSeg, EmptySeg, FilterSeg, Plan,
     ScanStep, core_filter_exprs,
 )
 from repro.core.jexec import (
-    JBindings, bounds_from_plan, check_spine, device_distinct,
-    device_filter, device_join, device_left_join, device_order,
-    device_project, device_resize, device_scan, device_scan_tt,
-    device_slice, device_union, double_caps, prepare_value_keys, _compact,
+    JBindings, bound_scan_seed, bounds_from_plan, build_key, check_spine,
+    device_distinct, device_filter, device_join, device_left_join,
+    device_order, device_project, device_resize, device_scan,
+    device_scan_tt, device_slice, device_union, double_caps,
+    join_estimates, prefix_sum, prepare_value_keys, take_rows, _compact,
     _exec_cols, _mod_cap_seed, _step_meta, _tt_meta, _valid_mask,
 )
 from repro.core.modifiers import ModifierSpine, filter_const_slots
@@ -63,17 +59,13 @@ __all__ = ["DistBindings", "DistributedExecutor", "shard_table",
 
 
 def _smap(body, mesh, in_specs, out_specs):
-    """shard_map with the replication check off where the kwarg exists:
-    the gathered modifier tail (sort/scatter over all_gather-ed, hence
-    replicated, relations) is replication-safe by construction, but not
-    every primitive in it carries a rep rule on every jax version."""
-    try:
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:          # newer jax: the check_rep kwarg is gone
-        # replint: disable=shard-map-check-rep -- the explicit decision is the check_rep=False attempt above; this branch only runs on jax versions that removed the kwarg (replication checking off by construction)
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+    """shard_map with the replication (vma) check off: the gathered
+    modifier tail (sort/scatter over all_gather-ed, hence replicated,
+    relations) returns replicated ``out_specs`` the checker cannot
+    prove, and ``pallas_call`` in the build-side pair grid has no
+    replication rule."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -121,38 +113,31 @@ def repartition(data: jax.Array, n: jax.Array, key_col: int, n_shards: int,
     dest = jnp.where(valid, key.astype(jnp.uint32) % n_shards, n_shards)
 
     bucket_cap = max(16, round_up_pow2(2 * cap // n_shards + 16))
-    # stable sort by destination groups rows; rank-within-group = slot
-    order = jnp.argsort(dest, stable=True).astype(jnp.int32)
-    sdest = dest[order]
-    sdata = data[order]
-    group_start = jnp.searchsorted(sdest, jnp.arange(n_shards + 1, dtype=dest.dtype),
-                                   side="left").astype(jnp.int32)
-    rank = jnp.arange(cap, dtype=jnp.int32) - group_start[sdest]
-    counts = group_start[1:] - group_start[:-1]          # per-dest counts
-    overflow = jnp.any(counts[:n_shards] > bucket_cap)
+    # slot of a row in its destination bucket = rows before it with the
+    # same destination, from one prefix count per destination (stable,
+    # and no sort: a sort costs the TPU compiler ~20 s per program)
+    rank = jnp.zeros(cap, jnp.int32)
+    counts = []
+    for d in range(n_shards):
+        hit = dest == d
+        seen = prefix_sum(hit)
+        rank = jnp.where(hit, seen - 1, rank)
+        counts.append(seen[-1])
+    overflow = jnp.any(jnp.stack(counts) > bucket_cap)
 
-    send = jnp.full((n_shards, bucket_cap, k), PAD, dtype=data.dtype)
-    in_bounds = (rank < bucket_cap) & (sdest < n_shards)
-    didx = jnp.where(in_bounds, sdest, n_shards).astype(jnp.int32)  # OOB -> drop
+    in_bounds = (rank < bucket_cap) & (dest < n_shards)
+    didx = jnp.where(in_bounds, dest, n_shards).astype(jnp.int32)  # OOB -> drop
     ridx = jnp.clip(rank, 0, bucket_cap - 1)
-    send = send.at[didx, ridx].set(sdata, mode="drop")
+    empty = jnp.full((n_shards, bucket_cap), PAD, dtype=data.dtype)
+    send = jnp.stack([empty.at[didx, ridx].set(data[:, c], mode="drop")
+                      for c in range(k)], axis=-1)
 
     recv = jax.lax.all_to_all(send, axis_name, split_axis=0, concat_axis=0,
                               tiled=False)
     recv = recv.reshape(n_shards * bucket_cap, k)
-    keep = recv[:, 0] != PAD
-    # compact into out_cap
-    n_keep = jnp.sum(keep, dtype=jnp.int32)
-    corder = jnp.argsort(~keep, stable=True)
-    gathered = recv[corder][:out_cap]
-    if gathered.shape[0] < out_cap:
-        padrows = jnp.full((out_cap - gathered.shape[0], k), PAD, gathered.dtype)
-        gathered = jnp.concatenate([gathered, padrows], axis=0)
-    mask = _valid_mask(out_cap, jnp.minimum(n_keep, out_cap))
-    gathered = jnp.where(mask[:, None], gathered, PAD)
-    overflow = overflow | (n_keep > out_cap)
-    overflow = jax.lax.pmax(overflow, axis_name)
-    return gathered, jnp.minimum(n_keep, out_cap), overflow
+    gathered, n_out, cut = _compact(recv, recv[:, 0] != PAD, out_cap)
+    overflow = jax.lax.pmax(overflow | cut, axis_name)
+    return gathered, n_out, overflow
 
 
 # ---------------------------------------------------------------------------
@@ -252,13 +237,16 @@ class DistributedExecutor:
                 return seed(seg.child)
             if isinstance(seg, BGPSeg):
                 est = 1.0
+                joined = join_estimates(seg.plan.steps, catalog)
                 for k, step in enumerate(seg.plan.steps):
                     i = seg.start + k
                     scan_est = max(1.0, sizes[i] / self.n_shards)
                     if step.tp.n_bound() > 1:
-                        scan_est = max(1.0, scan_est * 0.01)
+                        scan_est = max(1.0, bound_scan_seed(
+                            step, catalog, sizes[i]) / self.n_shards)
                     est = scan_est if k == 0 else \
-                        max(est, scan_est, est * 1.25)
+                        max(est, scan_est, est * 1.25,
+                            joined[k] / self.n_shards)
                     flat_caps[i] = round_up_pow2(int(est * slack) + 16, 16)
                 return est
             le, re_ = seed(seg.left), seed(seg.right)
@@ -380,7 +368,11 @@ class DistributedExecutor:
                 acc = cur
                 ovfs[i] = cur.overflow
                 continue
-            joined = self._dist_join(acc, cur, caps[i], axis)
+            # an s-copy scan partitioned by its subject keeps the table's
+            # (s, o) order: joined on that subject it needs no sort
+            sorted_by = step.tp.s if (not step.uses_tt and
+                                      self.scan_copy[i] == "s") else None
+            joined = self._dist_join(acc, cur, caps[i], axis, sorted_by)
             ovfs[i] = joined.overflow | cur.overflow
             acc = joined
         return DistBindings(acc.cols, acc.data, acc.n, no, acc.part_key)
@@ -472,10 +464,11 @@ class DistributedExecutor:
         return gb.data, gb.n[None], gb.n, out_ovf
 
     def _dist_join(self, a: DistBindings, b: DistBindings, out_cap: int,
-                   axis) -> DistBindings:
+                   axis, b_sorted_by: Optional[str] = None) -> DistBindings:
         """Join two shard-local relations; the returned ``overflow`` is
         this step's OWN flag (repartition bucket/compact + join output) —
-        input flags are not propagated, the caller tracks them per step."""
+        input flags are not propagated, the caller tracks them per step.
+        ``b_sorted_by`` names the column ``b``'s rows are ordered by."""
         no = jnp.asarray(False)
         shared = [c for c in a.cols if c in b.cols]
         if not shared:
@@ -494,13 +487,18 @@ class DistributedExecutor:
             da, na, o1 = repartition(da, na, a.cols.index(key), self.n_shards,
                                      axis, max(da.shape[0], out_cap))
             ovf |= o1
+        pre = None
         if b.part_key != key:
             db, nb, o2 = repartition(db, nb, b.cols.index(key), self.n_shards,
                                      axis, max(db.shape[0], out_cap))
             ovf |= o2
+        elif b_sorted_by == key:
+            pre = (jnp.arange(db.shape[0], dtype=jnp.int32),
+                   build_key(JBindings(b.cols, db, nb, no),
+                             b.cols.index(key)))
         jb = device_join(JBindings(a.cols, da, na, no),
                          JBindings(b.cols, db, nb, no),
-                         out_cap)
+                         out_cap, b_presorted=pre)
         return DistBindings(jb.cols, jb.data, jb.n, jb.overflow | ovf, key)
 
     def _dist_left_join(self, a: DistBindings, b: DistBindings,
@@ -595,8 +593,8 @@ class DistributedExecutor:
     @functools.cached_property
     def _jitted_batch(self):
         # Batched form: the (B, n_steps, 2) bounds stack is replicated to
-        # every shard and vmapped *inside* shard_map, so the batch axis
-        # rides alongside the data axis — every device executes all B
+        # every shard and looped over *inside* shard_map, so the batch
+        # axis rides alongside the data axis — every device executes all B
         # constant-bindings over its own table shard in one launch, and
         # results stay sharded per (request, shard) (or replicated per
         # request once a global modifier gathers them).
@@ -612,10 +610,9 @@ class DistributedExecutor:
 
         def wrapper(caps, bounds_b, fconsts_b, values, *flat):
             def shard_fn(bounds_b, fconsts_b, values, *flat):
-                return jax.vmap(
-                    lambda b, fc: self._shard_program(caps, b, fc, values,
-                                                      *flat)
-                )(bounds_b, fconsts_b)
+                return jax.lax.map(
+                    lambda bf: self._shard_program(caps, *bf, values, *flat),
+                    (bounds_b, fconsts_b))
 
             fn = _smap(
                 shard_fn,
@@ -768,31 +765,25 @@ def _extvp_pair_program(mesh: Mesh, axes: Tuple[str, ...], use_bitmap: bool,
     )
 
     body = batch_pair_masks_bitmap if use_bitmap else batch_pair_masks
-    specs = dict(in_specs=(P(), P(), P(axes), P(axes), P(axes), P(axes)),
-                 out_specs=(P(axes), P(axes)))
-    try:
-        # pallas_call has no replication rule; the body has no collectives,
-        # so skipping the check is sound
-        fn = _shard_map(body, mesh=mesh, check_rep=False, **specs)
-    except TypeError:           # newer jax: the check_rep kwarg is gone
-        # replint: disable=shard-map-check-rep -- the explicit decision is the check_rep=False attempt above; this branch only runs on jax versions that removed the kwarg (the body has no collectives)
-        fn = _shard_map(body, mesh=mesh, **specs)
-    return jax.jit(fn)
+    return jax.jit(_smap(body, mesh,
+                         in_specs=(P(), P(), P()) + (P(axes),) * 4,
+                         out_specs=(P(axes), P(axes))))
 
 
-def extvp_pair_masks_sharded(keys: jax.Array, build_operand: jax.Array,
-                             pcol: jax.Array, pidx: jax.Array,
+def extvp_pair_masks_sharded(uniq: jax.Array, mult: jax.Array,
+                             build_operand: jax.Array, pcol: jax.Array, pidx: jax.Array,
                              bcol: jax.Array, bidx: jax.Array, mesh: Mesh,
                              axes: Optional[Sequence[str]] = None,
                              use_bitmap: bool = False
                              ) -> Tuple[jax.Array, jax.Array]:
-    """Semi-join masks for a batch of packed ExtVP pairs with the
+    """Semi-join flags for a batch of packed ExtVP pairs with the
     (kind, p1, p2) pair grid partitioned across the mesh.
 
     S2RDF runs the §5 semi-join reductions as a distributed Spark job;
-    here the packed catalog (probe columns + the build-side operand —
-    sorted-unique tensor for the kernel path, dense presence bitmap when
-    ``use_bitmap``) is replicated and each device evaluates its B/S slice
+    here the packed catalog (sorted-unique keys, their multiplicities and
+    the build-side operand — the sorted-unique tensor again for the
+    kernel path, a dense presence bitmap when ``use_bitmap``) is
+    replicated and each device evaluates its B/S slice
     of the pair batch — the load-time counterpart of the query engine's
     sharded scans.  The batch size must divide evenly by the shard count
     (the planner in :mod:`repro.core.extvp_build` rounds it up).
@@ -804,7 +795,7 @@ def extvp_pair_masks_sharded(keys: jax.Array, build_operand: jax.Array,
                          f"across {n_shards} shards")
     from repro.kernels.ops import pallas_enabled
     return _extvp_pair_program(mesh, axes, use_bitmap, pallas_enabled())(
-        keys, build_operand, pcol, pidx, bcol, bidx)
+        uniq, mult, build_operand, pcol, pidx, bcol, bidx)
 
 
 def _allgather_relation(b: DistBindings, axis):
@@ -817,4 +808,4 @@ def _allgather_relation(b: DistBindings, axis):
         jnp.arange(b.data.shape[0], dtype=jnp.int32) < b.n,
         axis, axis=0, tiled=True)
     n_tot = jax.lax.psum(b.n, axis)
-    return data[jnp.argsort(~keep, stable=True)], n_tot
+    return _compact(data, keep, data.shape[0])[0], n_tot

@@ -4,16 +4,18 @@ The numpy builder in :mod:`repro.core.vp` walks all P²·3 (kind, p1, p2)
 pairs in a Python loop, one host semi-join per pair.  This module is the
 device analogue of S2RDF's distributed Spark load job:
 
-* the VP catalog is packed **once** into padded per-predicate column
-  tensors (``PackedVP``) — the per-predicate sort/unique work is hoisted
-  out of the pair loop into the packing step;
-* semi-join masks for whole **batches** of pairs are evaluated in one
-  vmapped pass: through the tiled :func:`repro.kernels.ops.semijoin_mask`
-  kernel when the Pallas path is enabled, and through a packed
-  **presence bitmap** otherwise — dictionary ids are dense, so build-side
-  membership is a single O(1) gather per probe key (the device analogue
-  of a hash set; XLA's searchsorted is a poor fit for batched CPU
-  probes);
+* the VP catalog is packed **once** into padded per-predicate
+  sorted-unique key tensors (``PackedVP``) — the per-predicate
+  sort/unique work is hoisted out of the pair loop into the packing step;
+* semi-joins for whole **batches** of pairs are decided once per unique
+  probe key in one pass: through the banded
+  :func:`repro.kernels.ops.semijoin_mask` kernel when the Pallas path is
+  enabled, and through a packed **presence bitmap** otherwise —
+  dictionary ids are dense, so build-side membership is a single O(1)
+  gather per probe key (the device analogue of a hash set; XLA's
+  searchsorted is a poor fit for batched CPU probes).  Key
+  multiplicities turn the flags into SF; only the pairs that
+  materialize bring their flags back to expand into row masks;
 * a ``shard_map`` variant (:func:`repro.core.distributed
   .extvp_pair_masks_sharded`) partitions the pair grid across the mesh,
   so a multi-device build evaluates P²·3/S pairs per device.
@@ -55,6 +57,9 @@ __all__ = [
 #: path (2 · P · V bool cells ≈ bytes; 2^28 ≈ 256 MB)
 BITMAP_CELL_LIMIT = 1 << 28
 
+#: bound on a pair batch's (B, ucap) flag tensor: 2^26 int32 cells = 256 MB
+PAIR_CELLS = 1 << 26
+
 Key = Tuple[str, int, int]
 
 
@@ -74,28 +79,37 @@ def build_col(kind: str) -> int:
 
 @dataclass
 class PackedVP:
-    """The VP catalog as static-shape device tensors.
+    """The VP catalog's key sets as static-shape device tensors.
 
-    ``keys[c, i]`` is predicate-slot ``i``'s column ``c`` (0 = s, 1 = o)
-    in **row order** (so a semi-join mask indexes the table's rows
-    directly), padded with ``PROBE_PAD``.  ``uniq[c, i]`` is the sorted
-    unique values of that column, padded with ``BUILD_PAD`` (which sorts
-    above every valid id, keeping the padded array ascending).
-    ``present[c, i, v]`` is the same key set as a dense membership bitmap
-    (ids are dictionary-dense, so ``v`` indexes directly); ``None`` when
-    the id space is too large (``BITMAP_CELL_LIMIT``).
+    ``uniq[c, i]`` is the sorted unique values of predicate-slot ``i``'s
+    column ``c`` (0 = s, 1 = o), padded with ``BUILD_PAD`` (which sorts
+    above every valid id, keeping the padded array ascending);
+    ``mult[c, i]`` is how many rows hold each of them (0 on pads), and
+    ``inv[c][i]`` maps each row of the table to its key's position in
+    ``uniq[c, i]``.  A semi-join is decided once per unique probe key:
+    ``mult`` turns those flags into the reduced table's size and ``inv``
+    into its row mask.  ``present[c, i, v]`` is the key set as a dense
+    membership bitmap (ids are dictionary-dense, so ``v`` indexes
+    directly); ``None`` when the id space is too large
+    (``BITMAP_CELL_LIMIT``).
     """
 
     preds: Tuple[int, ...]          # slot -> predicate id
     slot: Dict[int, int]            # predicate id -> slot
-    keys: np.ndarray                # (2, P, cap) int32
     uniq: np.ndarray                # (2, P, ucap) int32
-    n: np.ndarray                   # (P,) int32 rows per predicate
+    mult: np.ndarray                # (2, P, ucap) int32
+    inv: Tuple[List[np.ndarray], List[np.ndarray]]   # [c][slot] -> (n,)
     present: Optional[np.ndarray]   # (2, P, V) bool, or None
+
+    def row_mask(self, kind: str, p1: int, member: np.ndarray) -> np.ndarray:
+        """Rows of ``p1``'s table whose probe key ``member`` flags, from
+        the per-unique-key flags of a pair (kind, p1, ·)."""
+        inv = self.inv[probe_col(kind)][self.slot[p1]]
+        return np.asarray(member)[inv].astype(bool)
 
 
 def pack_vp(vp: Dict[int, Table], with_bitmap: bool = True) -> PackedVP:
-    """Pack every VP table's columns + sorted-unique key sets.
+    """Pack every VP table's sorted-unique key sets and multiplicities.
 
     This is the hoisted per-predicate work: each ``unique_s``/``unique_o``
     sort happens once here instead of once per pair in the build loop
@@ -105,21 +119,20 @@ def pack_vp(vp: Dict[int, Table], with_bitmap: bool = True) -> PackedVP:
     """
     preds = tuple(sorted(vp))
     n_preds = len(preds)
-    cap = round_up_pow2(max((len(vp[p]) for p in preds), default=1))
     ucap = round_up_pow2(max(
         (max(len(vp[p].unique_s), len(vp[p].unique_o)) for p in preds),
         default=1))
-    keys = np.full((2, n_preds, cap), ops.PROBE_PAD, dtype=np.int32)
     uniq = np.full((2, n_preds, ucap), ops.BUILD_PAD, dtype=np.int32)
-    n = np.zeros(n_preds, dtype=np.int32)
+    mult = np.zeros((2, n_preds, ucap), dtype=np.int32)
+    inv: Tuple[List[np.ndarray], List[np.ndarray]] = ([], [])
     max_id = 0
     for i, p in enumerate(preds):
         t = vp[p]
-        n[i] = len(t)
-        keys[0, i, :len(t)] = t.s
-        keys[1, i, :len(t)] = t.o
-        uniq[0, i, :len(t.unique_s)] = t.unique_s
-        uniq[1, i, :len(t.unique_o)] = t.unique_o
+        for c, (col, u) in enumerate(((t.s, t.unique_s), (t.o, t.unique_o))):
+            pos = np.searchsorted(u, col).astype(np.int32)
+            uniq[c, i, :len(u)] = u
+            mult[c, i, :len(u)] = np.bincount(pos, minlength=len(u))
+            inv[c].append(pos)
         if len(t):
             max_id = max(max_id, int(t.unique_s[-1]), int(t.unique_o[-1]))
     volume = round_up_pow2(max_id + 1)
@@ -130,7 +143,7 @@ def pack_vp(vp: Dict[int, Table], with_bitmap: bool = True) -> PackedVP:
             present[0, i, vp[p].unique_s] = True
             present[1, i, vp[p].unique_o] = True
     return PackedVP(preds=preds, slot={p: i for i, p in enumerate(preds)},
-                    keys=keys, uniq=uniq, n=n, present=present)
+                    uniq=uniq, mult=mult, inv=inv, present=present)
 
 
 # ---------------------------------------------------------------------------
@@ -167,47 +180,50 @@ def plan_pairs(vp: Dict[int, Table],
 
 
 # ---------------------------------------------------------------------------
-# Device evaluation: one vmapped pass per pair batch
+# Device evaluation: one pass per pair batch
 # ---------------------------------------------------------------------------
 
-def batch_pair_masks(keys: jax.Array, uniq: jax.Array,
+def batch_pair_masks(uniq: jax.Array, mult: jax.Array, build: jax.Array,
                      pcol: jax.Array, pidx: jax.Array,
                      bcol: jax.Array, bidx: jax.Array
                      ) -> Tuple[jax.Array, jax.Array]:
-    """Semi-join masks + counts for a batch of packed pairs, one vmapped
-    pass over the :func:`repro.kernels.ops.semijoin_mask` kernel (tiled
-    Pallas broadcast-compare when enabled, jnp searchsorted otherwise).
+    """Semi-join flags + reduced sizes for a batch of packed pairs, one
+    batched :func:`repro.kernels.ops.semijoin_mask` call (the banded
+    Pallas sweep when enabled, jnp searchsorted otherwise).
 
-    For slot ``j``: probe = ``keys[pcol[j], pidx[j]]`` (row order),
-    build = ``uniq[bcol[j], bidx[j]]`` (ascending).  Padded probe lanes
-    (``PROBE_PAD``) never match padded or real build keys, so the count
-    is exact.  Also the per-shard body of the distributed pair grid."""
-    def one(pc, pi, bc, bi):
-        return ops.semijoin_mask(keys[pc, pi], uniq[bc, bi])
+    For slot ``j``: probe = ``uniq[pcol[j], pidx[j]]``, build =
+    ``build[bcol[j], bidx[j]]`` (the same packed ``uniq`` tensor), both
+    ascending.  Returns ``member[j, u]`` — is the probe side's ``u``-th
+    unique key in the build side — and the pair's row count
+    ``Σ_u member · mult``.  Probe pads become ``PROBE_PAD`` and never
+    match.  Also the per-shard body of the distributed pair grid."""
+    probe = uniq[pcol, pidx]
+    probe = jnp.where(probe == ops.BUILD_PAD, ops.PROBE_PAD, probe)
+    member = ops.semijoin_mask(probe, build[bcol, bidx])    # (B, ucap)
+    return member, (member * mult[pcol, pidx]).sum(axis=1, dtype=jnp.int32)
 
-    masks = jax.vmap(one)(pcol, pidx, bcol, bidx)      # (B, cap) int32
-    return masks, masks.sum(axis=1, dtype=jnp.int32)
 
-
-def batch_pair_masks_bitmap(keys: jax.Array, present: jax.Array,
+def batch_pair_masks_bitmap(uniq: jax.Array, mult: jax.Array,
+                            present: jax.Array,
                             pcol: jax.Array, pidx: jax.Array,
                             bcol: jax.Array, bidx: jax.Array
                             ) -> Tuple[jax.Array, jax.Array]:
     """Bitmap variant of :func:`batch_pair_masks`: build-side membership
-    is one gather per probe key into the dense presence bitmap — the
-    device analogue of a hash-set probe, and the fast default off-TPU
-    where a batched binary search gathers log₂(ucap) times instead.
-    Probe pads (``PROBE_PAD`` ≥ V) fall out via the ``< V`` guard."""
+    is one gather per unique probe key into the dense presence bitmap —
+    the device analogue of a hash-set probe, and the fast default
+    off-TPU where a batched binary search gathers log₂(ucap) times
+    instead.  Probe pads (``BUILD_PAD`` ≥ V) fall out via the ``< V``
+    guard."""
     volume = present.shape[-1]
 
     def one(pc, pi, bc, bi):
-        probe = keys[pc, pi]
-        bitmap = present[bc, bi]
-        hit = bitmap[jnp.clip(probe, 0, volume - 1)] & (probe < volume)
+        probe = uniq[pc, pi]
+        hit = present[bc, bi][jnp.clip(probe, 0, volume - 1)] & \
+            (probe < volume)
         return hit.astype(jnp.int32)
 
-    masks = jax.vmap(one)(pcol, pidx, bcol, bidx)      # (B, cap) int32
-    return masks, masks.sum(axis=1, dtype=jnp.int32)
+    member = jax.vmap(one)(pcol, pidx, bcol, bidx)     # (B, ucap)
+    return member, (member * mult[pcol, pidx]).sum(axis=1, dtype=jnp.int32)
 
 
 @functools.lru_cache(maxsize=None)
@@ -220,12 +236,16 @@ def _jitted_batch_fn(use_bitmap: bool, pallas: bool):
     return jax.jit(lambda *args: body(*args))
 
 
-def _batch_size(n_pairs: int, pair_batch: int, n_shards: int) -> int:
-    """Static batch shape: power-of-two sized (bounding compile count)
-    and never above the caller's ``pair_batch`` bound, then rounded up to
-    a multiple of the shard count (the one case that may exceed it)."""
-    b = round_up_pow2(min(max(n_pairs, 1), pair_batch), minimum=8)
-    if b > pair_batch and b > 8:
+def _batch_size(n_pairs: int, pair_batch: int, n_shards: int,
+                ucap: int = 1) -> int:
+    """Static batch shape: power-of-two sized (bounding compile count),
+    never above the caller's ``pair_batch`` bound nor above
+    ``PAIR_CELLS // ucap`` (the batch's (B, ucap) tensors stay bounded
+    on large graphs), then rounded up to a multiple of the shard count
+    (the one case that may exceed either bound)."""
+    bound = max(8, min(pair_batch, PAIR_CELLS // ucap))
+    b = round_up_pow2(min(max(n_pairs, 1), bound), minimum=8)
+    if b > bound and b > 8:
         b //= 2
     return -(-b // n_shards) * n_shards
 
@@ -282,9 +302,11 @@ def evaluate_pairs(vp: Dict[int, Table], evals: Sequence[Key],
     used = {p for k in evals for p in (k[1], k[2])}
     packed = pack_vp({p: vp[p] for p in used}, with_bitmap=not pallas)
     use_bitmap = packed.present is not None and not pallas
-    operand = jnp.asarray(packed.present if use_bitmap else packed.uniq)
-    jkeys = jnp.asarray(packed.keys)
-    batch = _batch_size(len(evals), pair_batch, n_shards)
+    juniq = jnp.asarray(packed.uniq)
+    jmult = jnp.asarray(packed.mult)
+    operand = jnp.asarray(packed.present) if use_bitmap else juniq
+    batch = _batch_size(len(evals), pair_batch, n_shards,
+                        packed.uniq.shape[-1])
     pcol = np.array([probe_col(k[0]) for k in evals], dtype=np.int32)
     pidx = np.array([packed.slot[k[1]] for k in evals], dtype=np.int32)
     bcol = np.array([build_col(k[0]) for k in evals], dtype=np.int32)
@@ -301,23 +323,30 @@ def evaluate_pairs(vp: Dict[int, Table], evals: Sequence[Key],
         args = [jnp.asarray(a) for a in parts]
         if backend == "distributed":
             from repro.core.distributed import extvp_pair_masks_sharded
-            masks, counts = extvp_pair_masks_sharded(
-                jkeys, operand, *args, mesh=mesh, use_bitmap=use_bitmap)
+            member, counts = extvp_pair_masks_sharded(
+                juniq, jmult, operand, *args, mesh=mesh,
+                use_bitmap=use_bitmap)
         else:
-            masks, counts = _jitted_batch_fn(use_bitmap, pallas)(
-                jkeys, operand, *args)
-        # bulk bookkeeping: SF for the whole chunk in one vectorized pass,
-        # per-pair host work only where a table actually materializes
-        masks = np.asarray(masks)
+            member, counts = _jitted_batch_fn(use_bitmap, pallas)(
+                juniq, jmult, operand, *args)
+        # bulk bookkeeping: SF for the whole chunk in one vectorized pass;
+        # flags come back only for the pairs whose table materializes
         counts = np.asarray(counts)[:len(chunk)].astype(np.int64)
         n1s = np.array([len(vp[k[1]]) for k in chunk], dtype=np.int64)
         sfv = np.where(n1s > 0, counts / np.maximum(n1s, 1), 0.0)
         sf.update(zip(chunk, sfv.tolist()))
         sizes.update(zip(chunk, counts.tolist()))
-        for j in np.nonzero((sfv > 0) & (sfv < 1.0) & (sfv <= threshold))[0]:
-            key = chunk[j]
-            rows = vp[key[1]].rows
-            tables[key] = Table(rows[masks[j, :len(rows)].astype(bool)])
+        mat = np.nonzero((sfv > 0) & (sfv < 1.0) & (sfv <= threshold))[0]
+        if len(mat):
+            # gather on one device (a sharded batch would need the gather
+            # partitioned); only the materializing pairs cross to the host
+            member = jax.device_put(
+                member, jax.sharding.SingleDeviceSharding(jax.devices()[0]))
+            flags = np.asarray(member[jnp.asarray(mat)])
+            for j, flag in zip(mat.tolist(), flags):
+                kind, p1, _ = key = chunk[j]
+                rows = vp[p1].rows
+                tables[key] = Table(rows[packed.row_mask(kind, p1, flag)])
     return sf, sizes, tables
 
 

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import estimate
 from repro.core.algebra import BoolOp, Bound, Cmp, FilterExpr, NotExpr, is_var
 from repro.core.compiler import (
     BGPSeg, CombineSeg, CorePlan, CoreSeg, EmptySeg, FilterSeg, Plan,
@@ -203,20 +204,51 @@ def _valid_mask(cap: int, n: jax.Array) -> jax.Array:
     return jnp.arange(cap, dtype=jnp.int32) < n
 
 
+_SCAN_ROW = 1024
+
+
+def prefix_sum(x: jax.Array) -> jax.Array:
+    """Inclusive int32 prefix sum, in two levels for long inputs: row
+    sums over a ``(n / 1024, 1024)`` view plus the prefix of the row
+    totals.  The TPU compiler spends ~10 s on one cumsum over 10^6
+    elements and well under a second on the two-level form."""
+    x = x.astype(jnp.int32)
+    n = x.shape[0]
+    if n <= _SCAN_ROW or n % _SCAN_ROW:
+        return jnp.cumsum(x)
+    rows = jnp.cumsum(x.reshape(n // _SCAN_ROW, _SCAN_ROW), axis=1)
+    totals = rows[:, -1]
+    return (rows + (prefix_sum(totals) - totals)[:, None]).reshape(n)
+
+
+def take_rows(data: jax.Array, idx: jax.Array) -> jax.Array:
+    """``data[idx]`` for a ``(cap, k)`` relation, as one 1-D gather per
+    column.  A row gather of the 2-D array puts each gathered row in the
+    TPU's 128 lanes — k int32 columns padded 128/k-fold, gigabytes at
+    10^7-row capacities — where a column gather stays lane-dense."""
+    if data.shape[1] == 0:
+        return data[idx]
+    return jnp.stack([data[:, c][idx] for c in range(data.shape[1])], axis=1)
+
+
 def _compact(data: jax.Array, keep: jax.Array, out_cap: int,
              fill: int = PAD) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Move keep-rows to the front (stable); returns (data, n, overflow)."""
+    """Move keep-rows to the front (stable); returns (data, n, overflow).
+
+    Output slot ``j`` takes the first row whose running keep-count
+    exceeds ``j`` (a binary search over the prefix sum), so compaction
+    needs no sort: a sort of a 10^5-row or larger buffer costs the TPU
+    compiler ~20 s per program."""
     cap = data.shape[0]
-    n_keep = jnp.sum(keep, dtype=jnp.int32)
-    order = jnp.argsort(~keep, stable=True)           # keeps first
-    gathered = data[order]
-    if out_cap < cap:
-        gathered = gathered[:out_cap]
-    elif out_cap > cap:
-        padrows = jnp.full((out_cap - cap, data.shape[1]), fill, jnp.int32)
-        gathered = jnp.concatenate([gathered, padrows], axis=0)
-    mask = _valid_mask(out_cap, n_keep)
-    gathered = jnp.where(mask[:, None], gathered, fill)
+    if cap == 0:
+        return (jnp.full((out_cap, data.shape[1]), fill, jnp.int32),
+                jnp.int32(0), jnp.asarray(False))
+    running = prefix_sum(keep)
+    n_keep = running[-1]
+    j = jnp.arange(out_cap, dtype=jnp.int32)
+    src = jnp.searchsorted(running, j, side="right").astype(jnp.int32)
+    gathered = take_rows(data, jnp.clip(src, 0, cap - 1))
+    gathered = jnp.where((j < n_keep)[:, None], gathered, fill)
     return gathered, jnp.minimum(n_keep, out_cap), n_keep > out_cap
 
 
@@ -272,7 +304,7 @@ def device_scan_windowed(rows: jax.Array, n: jax.Array, s_bound,
     hi = jnp.searchsorted(col, sb, side="right").astype(jnp.int32)
     idx = lo + jnp.arange(out_cap, dtype=jnp.int32)
     keep = idx < hi
-    g = rows[jnp.clip(idx, 0, cap - 1)]
+    g = take_rows(rows, jnp.clip(idx, 0, cap - 1))
     projected = g[:, list(out_cols)] if out_cols else g[:, :0]
     data = jnp.where(keep[:, None], projected, PAD)
     return data, jnp.minimum(hi - lo, out_cap), hi - lo > out_cap
@@ -301,7 +333,8 @@ def _join_expand(a: JBindings, b: JBindings, out_cap: int,
         total = a.n * b.n
         valid = ii < total
         data = jnp.concatenate(
-            [a.data[a_idx], b.data[jnp.clip(b_idx, 0, cap_b - 1)]], axis=1)
+            [take_rows(a.data, a_idx),
+             take_rows(b.data, jnp.clip(b_idx, 0, cap_b - 1))], axis=1)
         return out_cols, data, a_idx, valid, total, False
 
     ka = a.data[:, a.cols.index(shared[0])]
@@ -316,7 +349,7 @@ def _join_expand(a: JBindings, b: JBindings, out_cap: int,
     lo = jnp.searchsorted(kb_sorted, ka, side="left").astype(jnp.int32)
     hi = jnp.searchsorted(kb_sorted, ka, side="right").astype(jnp.int32)
     cnt = hi - lo
-    prefix = jnp.cumsum(cnt) - cnt               # exclusive prefix
+    prefix = prefix_sum(cnt) - cnt               # exclusive prefix
     total = prefix[-1] + cnt[-1]
 
     j = jnp.arange(out_cap, dtype=jnp.int32)
@@ -328,8 +361,8 @@ def _join_expand(a: JBindings, b: JBindings, out_cap: int,
     b_idx = order_b[b_pos]
     valid = j < total
 
-    left = a.data[a_idx]
-    right = b.data[b_idx]
+    left = take_rows(a.data, a_idx)
+    right = take_rows(b.data, b_idx)
 
     # post-filter shared columns beyond the key (SQL NULL semantics)
     for c in shared[1:]:
@@ -351,7 +384,7 @@ def device_join(a: JBindings, b: JBindings, out_cap: int,
 
     ``b_presorted`` is an optional ``(order_b, kb_sorted)`` pair from
     :func:`build_key` + sort, letting callers hoist the O(n log n)
-    build-side sort out of a vmapped batch when ``b`` does not depend on
+    build-side sort out of a batched program when ``b`` does not depend on
     the bound constants."""
     out_cols, data, _, valid, total, needs_compact = _join_expand(
         a, b, out_cap, b_presorted)
@@ -608,7 +641,7 @@ def device_distinct(b: JBindings) -> JBindings:
     keys = [b.data[:, j] for j in range(k - 1, -1, -1)]
     keys.append((~valid).astype(jnp.int32))        # valid rows first
     order = jnp.lexsort(keys)
-    sdata = b.data[order]
+    sdata = take_rows(b.data, order)
     svalid = valid[order]
     same_prev = jnp.concatenate([
         jnp.zeros((1,), bool),
@@ -652,7 +685,7 @@ def device_order(b: JBindings, keys: Sequence[Tuple[str, bool]],
         return b
     ks.append((~valid).astype(jnp.int32))          # valid rows first
     order = jnp.lexsort(ks)
-    return JBindings(b.cols, b.data[order], b.n, b.overflow)
+    return JBindings(b.cols, take_rows(b.data, order), b.n, b.overflow)
 
 
 def device_slice(b: JBindings, offset: int, limit: Optional[int]) -> JBindings:
@@ -726,6 +759,43 @@ def _step_cols(step: ScanStep) -> Tuple[str, ...]:
     return _step_meta(step)[4]
 
 
+def _bgp_segs(seg: CoreSeg):
+    if isinstance(seg, BGPSeg):
+        yield seg
+    elif isinstance(seg, FilterSeg):
+        yield from _bgp_segs(seg.child)
+    elif isinstance(seg, CombineSeg):
+        yield from _bgp_segs(seg.left)
+        yield from _bgp_segs(seg.right)
+
+
+def _presorted_build_scans(root: CoreSeg) -> Tuple[Dict[int, str], set]:
+    """Join scans whose rows already come out in join-key order, so the
+    build side needs no sort (a sort of a 10^5-row or larger buffer
+    costs the TPU compiler ~20 s per program).  Tables are (s, o)-sorted:
+    a scan keyed on its subject, or on its object under a bound subject,
+    is in key order as stored; one keyed on its object otherwise reads
+    the (o, s)-sorted copy.  Either order is exactly what the stable
+    build-side sort would produce, so results are unchanged.
+
+    Returns ({flat step: join key}, {flat steps that read the o-sorted
+    copy})."""
+    keys: Dict[int, str] = {}
+    by_o = set()
+    for seg in _bgp_segs(root):
+        acc: List[str] = []
+        for k, step in enumerate(seg.plan.steps):
+            cols = _step_cols(step)
+            key = next((c for c in acc if c in cols), None)
+            tp = step.tp
+            if k and key is not None and not step.uses_tt:
+                if key == tp.o and is_var(tp.s) and tp.s != tp.o:
+                    by_o.add(seg.start + k)
+                keys[seg.start + k] = key
+            acc.extend(c for c in cols if c not in acc)
+    return keys, by_o
+
+
 _TRACE_COUNT = 0   # program traces (== XLA compiles); test probe
 
 
@@ -784,6 +854,36 @@ def _mod_cap_seed(spine: ModifierSpine, pipeline_cap: int) -> int:
         est = 4096
     est = max(est, pipeline_cap // 32)
     return min(round_up_pow2(est, 64), round_up_pow2(pipeline_cap, 64))
+
+
+def bound_scan_seed(step: ScanStep, catalog: Catalog, size: float) -> float:
+    """Capacity seed of a scan with a bound subject or object: the rows
+    a data-distributed constant is expected to match
+    (:func:`repro.core.estimate.scan_estimate`, from the catalog's
+    distinct-count and skew statistics) and never below 1% of the table.
+    A low seed costs one recompile per doubling on the chip."""
+    seed = size * 0.01
+    if estimate.supports(catalog):
+        seed = max(seed, estimate.scan_estimate(step, catalog)[0])
+    return max(1.0, seed)
+
+
+#: ceiling of a statistics-derived capacity seed (rows); beyond it the
+#: overflow retries grow the slot from the measured result
+MAX_ESTIMATE_SEED = float(1 << 24)
+
+
+def join_estimates(steps: Sequence[ScanStep], catalog: Catalog
+                   ) -> List[float]:
+    """Per-step intermediate rows of a left-deep scan/join pipeline from
+    the catalog's statistics (:func:`repro.core.estimate.estimate_order`),
+    0 where there are none.  A chain whose fan-out the 1.25-per-join
+    rule misses (WatDiv C1 grows ~500-fold) otherwise pays one overflow
+    retry — one recompile on the chip — per doubling."""
+    est = estimate.estimate_order(steps, catalog) if steps else None
+    if est is None:
+        return [0.0] * len(steps)
+    return [min(e.rows, MAX_ESTIMATE_SEED) for e in est]
 
 
 def double_caps(caps: Tuple[int, ...], ovf, n_steps: int) -> Tuple[int, ...]:
@@ -890,6 +990,7 @@ class PlanExecutor:
             else catalog.table(step.kind, int(step.tp.p), step.p2)
             for step in self.plan.steps]
         self._has_tt = any(s.uses_tt for s in self.plan.steps)
+        self._join_key, self._by_o = _presorted_build_scans(core.root)
         n_flat = len(self.plan.steps)
         flat_caps = [16] * n_flat
         comb_caps: List[int] = []
@@ -902,15 +1003,16 @@ class PlanExecutor:
                 return seed(seg.child)
             if isinstance(seg, BGPSeg):
                 est = 1.0
+                joined = join_estimates(seg.plan.steps, catalog)
                 for k, step in enumerate(seg.plan.steps):
                     i = seg.start + k
                     size = catalog.n_triples if step.uses_tt \
                         else len(self.tables[i])
                     scan_est = max(1.0, float(size))
                     if step.tp.n_bound() > 1:
-                        scan_est = max(1.0, scan_est * 0.01)
+                        scan_est = bound_scan_seed(step, catalog, size)
                     est = scan_est if k == 0 else \
-                        max(est, scan_est, est * 1.25)
+                        max(est, scan_est, est * 1.25, joined[k])
                     flat_caps[i] = round_up_pow2(int(est * slack) + 8, 16)
                 return est
             le, re_ = seed(seg.left), seed(seg.right)
@@ -997,6 +1099,16 @@ class PlanExecutor:
                                        same, take, out_cap)
         return JBindings(cols, data, n, ovf)
 
+    def _presorted(self, i: int, cur: JBindings
+                   ) -> Optional[Tuple[jax.Array, jax.Array]]:
+        """``device_join``'s ``b_presorted`` for a build scan already in
+        join-key order (:func:`_presorted_build_scans`), else None."""
+        key = self._join_key.get(i)
+        if key is None:
+            return None
+        return (jnp.arange(cur.capacity, dtype=jnp.int32),
+                build_key(cur, cur.cols.index(key)))
+
     def _compose_bgp(self, seg: BGPSeg, caps: Tuple[int, ...],
                      table_rows: List[jax.Array], table_ns: List[jax.Array],
                      tt_rows: jax.Array, tt_n: jax.Array, bounds: jax.Array,
@@ -1025,7 +1137,7 @@ class PlanExecutor:
             else:
                 cur = self._scan_step(i, step, k == 0, table_rows, table_ns,
                                       tt_rows, tt_n, bounds, caps)
-                pre = None
+                pre = self._presorted(i, cur)
             if acc is None:
                 acc = cur
                 ovfs[i] = cur.overflow
@@ -1107,7 +1219,10 @@ class PlanExecutor:
         hot path must not re-pad and re-transfer O(table) bytes on every
         launch."""
         rows = [jnp.zeros((0, 2), jnp.int32) if t is None
-                else jnp.asarray(t.to_device().rows) for t in self.tables]
+                else jnp.asarray(pad_rows(
+                    t.rows_by_o if i in self._by_o else t.rows,
+                    round_up_pow2(len(t))))
+                for i, t in enumerate(self.tables)]
         ns = [jnp.asarray(np.int32(0 if t is None else len(t)))
               for t in self.tables]
         if self._has_tt:
@@ -1137,8 +1252,8 @@ class PlanExecutor:
         Constants only enter scan *selection values*, so any step whose
         triple pattern binds no constant produces the same relation for
         every batch element.  Those scans — and the build-side sort of
-        the joins that consume them — are hoisted OUT of the vmap (per
-        BGP segment) and computed once per launch; only the
+        the joins that consume them — are hoisted OUT of the per-binding
+        loop (per BGP segment) and computed once per launch; only the
         constant-dependent scans and the (capacity-bounded, small)
         probe/expand/combine phases replicate per element.  This is what
         makes a batch ~O(shared + B·small) instead of B times the full
@@ -1183,8 +1298,8 @@ class PlanExecutor:
                     # the join key device_join will pick: first
                     # accumulated column present on the build side
                     key = next((c for c in acc_cols if c in cols), None)
-                    pre = None
-                    if key is not None:
+                    pre = self._presorted(i, cur)
+                    if pre is None and key is not None:
                         kb = build_key(cur, cols.index(key))
                         order_b = jnp.argsort(kb).astype(jnp.int32)
                         pre = (order_b, kb[order_b])
@@ -1207,7 +1322,10 @@ class PlanExecutor:
                 stacked = jnp.concatenate([stacked, mod_ovf[None]])
             return jb.data, jb.n, stacked
 
-        return jax.vmap(one)(bounds_b, fconsts_b)
+        # one binding after another inside the launch: a vmapped batch
+        # axis lands minor in the TPU layout of the join prefix sums and
+        # pads every (B, cap) buffer 128/B-fold
+        return jax.lax.map(lambda bf: one(*bf), (bounds_b, fconsts_b))
 
     @functools.cached_property
     def _jitted_batch(self):

@@ -80,6 +80,16 @@ class ExtVPBuild:
         """
         return sum(1 for v in self.sf.values() if lo < v <= hi and v < 1.0)
 
+    def identical(self, other: "ExtVPBuild") -> bool:
+        """Same statistics, same materialized tables row for row, same
+        semi-join count — what every build backend must reproduce."""
+        return (self.sf == other.sf and self.sizes == other.sizes
+                and set(self.tables) == set(other.tables)
+                and all(np.array_equal(self.tables[k].rows,
+                                       other.tables[k].rows)
+                        for k in self.tables)
+                and self.n_semijoins == other.n_semijoins)
+
     def total_tuples(self) -> int:
         # lazy table providers answer from their length metadata so
         # accounting never forces a load (see table.LazyTableMap)

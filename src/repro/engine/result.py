@@ -74,9 +74,16 @@ class Result:
         n = len(self)
         if not order:
             return Counter({(): n}) if n else Counter()
+        return Counter(map(tuple, self._columns(order).tolist()))
+
+    def _columns(self, cols: Sequence[str]) -> np.ndarray:
+        """The (n, len(cols)) int64 matrix over ``cols``, UNBOUND-filled
+        where this relation does not carry a column."""
+        n = len(self)
         arrs = [self.bindings.data[:, self.cols.index(c)] if c in self.cols
-                else np.full(n, UNBOUND, dtype=np.int32) for c in order]
-        return Counter(map(tuple, np.stack(arrs, axis=1).tolist()))
+                else np.full(n, UNBOUND) for c in cols]
+        return np.ascontiguousarray(np.stack(arrs, axis=1), np.int64) \
+            if arrs else np.zeros((n, 0), np.int64)
 
     def same_as(self, other: "Result") -> bool:
         """Multiset equality under SPARQL bag semantics.  Both sides are
@@ -86,4 +93,13 @@ class Result:
         binding strictly more (all-UNBOUND) columns was never equal to
         one omitting them, which let left-join tests pass vacuously."""
         cols = sorted(set(self.cols) | set(other.cols))
-        return self.as_multiset(cols) == other.as_multiset(cols)
+        a, b = self._columns(cols), other._columns(cols)
+        if a.shape != b.shape:
+            return False
+        if not cols:
+            return True
+        # equal bags <=> equal sorted rows; one sort of whole rows as
+        # byte strings compares 10^7-row results in seconds
+        row = np.dtype((np.void, a.itemsize * a.shape[1]))
+        return bool(np.array_equal(np.sort(a.view(row).ravel()),
+                                   np.sort(b.view(row).ravel())))

@@ -1,26 +1,23 @@
 """Pallas TPU kernel: sort-merge join probe (lower-bound + match count).
 
-The join expansion in :mod:`repro.core.jexec` needs, per probe key,
-``lo[i] = #{b < a_i}`` (the lower-bound rank into the sorted build side)
-and ``cnt[i] = #{b == a_i}``.  XLA lowers ``jnp.searchsorted`` to a
-33-step while-loop of dynamic-slices per key — serial, gather-bound and
-hostile to the VPU.  This kernel instead computes both quantities as
-*tiled compare-and-reduce sums*:
+The join expansion needs, per probe key, ``lo[i] = #{b < a_i}`` (the
+lower-bound rank into the sorted build side) and ``cnt[i] = #{b == a_i}``.
+XLA lowers ``jnp.searchsorted`` to a 33-step while-loop of dynamic-slices
+per key — serial, gather-bound and hostile to the VPU.  This kernel
+computes both as compare-and-count sums over the probe tile's band
+(:mod:`repro.kernels.band`):
 
-    lo[i]  = Σ_tiles Σ_j (b_j <  a_i)
-    cnt[i] = Σ_tiles Σ_j (b_j == a_i)
+    lo[i]  = start + Σ_{band} (b_j <  a_i)
+    cnt[i] =         Σ_{band} (b_j == a_i)
 
-over the same (A_tiles × B_tiles) grid as the semi-join kernel, with the
-same sorted-tile short-cuts: a build tile entirely below the probe tile
-contributes the scalar TB to every lo[i] (no vector compare); a build
-tile entirely above contributes nothing; only diagonal-band tiles do the
-(TA, TB) VPU compare.  Effective vector work is O(diag · TA · TB), i.e.
-linear in the input for sorted inputs, while staying branch-free inside
-each program.
+Every build key before the band is below the whole tile, so it adds the
+constant ``start`` to each rank; every key after it is above the tile and
+adds nothing.  Each build key of the band is broadcast from SMEM against
+the ``(8, 128)`` probe vreg.
 
-Padding: probe pads are 2^31-1, build pads 2^31-2, so pad counts never
-contaminate valid lanes (build pads are never < or == a valid probe key,
-and probe-pad lanes are discarded by the caller).
+Padding: probe pads are 2^31-1, build pads 2^31-2.  Pads never widen a
+band, so valid lanes see no pad; pad lanes carry garbage that callers
+discard.
 """
 
 from __future__ import annotations
@@ -31,69 +28,38 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.band import CHUNK, TILE, band_call, band_fold, tile_bands
+
 __all__ = ["join_probe_kernel", "join_probe_pallas", "TILE_A", "TILE_B"]
 
-TILE_A = 1024
-TILE_B = 512
+TILE_A = TILE        # probe keys per grid step
+TILE_B = CHUNK       # build-length multiple (one HBM -> SMEM copy)
 
 
-def join_probe_kernel(a_ref, b_ref, lo_ref, cnt_ref):
-    j = pl.program_id(1)
+def join_probe_kernel(start_ref, end_ref, a_ref, b_hbm, lo_ref, cnt_ref,
+                      buf, sem):
+    g = pl.program_id(0)
+    start = start_ref[g]
+    a = a_ref[...]                                  # (8, 128) int32
 
-    @pl.when(j == 0)
-    def _init():
-        lo_ref[...] = jnp.zeros_like(lo_ref)
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+    def step(acc, b):
+        lt, eq = acc
+        return lt + (b < a).astype(jnp.int32), eq + (b == a).astype(jnp.int32)
 
-    a = a_ref[...]            # (1, TA) (any order)
-    b = b_ref[...]            # (1, TB) ascending
-    a_lo, a_hi = jnp.min(a), jnp.max(a)   # probe tile need not be sorted
-    b_lo, b_hi = b[0, 0], b[0, -1]        # build side is globally ascending
-
-    below = b_hi < a_lo       # whole build tile strictly below probe tile
-
-    @pl.when(below)
-    def _all_below():
-        lo_ref[...] = lo_ref[...] + jnp.int32(b.shape[1])
-
-    overlap = jnp.logical_and(jnp.logical_not(below), b_lo <= a_hi)
-
-    @pl.when(overlap)
-    def _compare():
-        av = a[0, :, None]                     # (TA, 1)
-        bv = b[0, None, :]                     # (1, TB)
-        lt = (bv < av).astype(jnp.int32)       # (TA, TB)
-        eq = (bv == av).astype(jnp.int32)
-        lo_ref[...] = lo_ref[...] + jnp.sum(lt, axis=1)[None, :]
-        cnt_ref[...] = cnt_ref[...] + jnp.sum(eq, axis=1)[None, :]
-    # (b_lo > a_hi): contributes nothing — fall through
+    zero = jnp.zeros(a.shape, jnp.int32)
+    lt, eq = band_fold(start, end_ref[g], b_hbm, buf, sem, step,
+                       (zero, zero))
+    lo_ref[...] = lt + start
+    cnt_ref[...] = eq
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def join_probe_pallas(probe: jax.Array, build: jax.Array,
                       interpret: bool = True):
     """Returns (lo, cnt) int32 arrays, shapes == probe.  Build ascending,
-    probe any order, tile-aligned (ops.py pads)."""
-    n_a, n_b = probe.shape[0], build.shape[0]
-    assert n_a % TILE_A == 0 and n_b % TILE_B == 0, (n_a, n_b)
-    grid = (n_a // TILE_A, n_b // TILE_B)
-
-    lo, cnt = pl.pallas_call(
-        join_probe_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, TILE_A), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, TILE_B), lambda i, j: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, TILE_A), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, TILE_A), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_a // TILE_A, TILE_A), jnp.int32),
-            jax.ShapeDtypeStruct((n_a // TILE_A, TILE_A), jnp.int32),
-        ],
-        interpret=interpret,
-    )(probe.reshape(n_a // TILE_A, TILE_A),
-      build.reshape(n_b // TILE_B, TILE_B))
-    return lo.reshape(n_a), cnt.reshape(n_a)
+    probe any order, lengths multiples of ``TILE_A`` / ``TILE_B``
+    (ops.py pads)."""
+    start, end = tile_bands(probe, build)
+    lo, cnt = band_call(join_probe_kernel, 2, probe, build, start, end,
+                        interpret)
+    return lo, cnt

@@ -25,15 +25,12 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.kernels import bucketcount, mergejoin, ref, semijoin
+from repro.kernels.band import BUILD_PAD, PROBE_PAD, tile_bands
 
 __all__ = ["semijoin_mask", "join_probe", "bucket_count", "use_pallas",
            "pallas_enabled", "PROBE_PAD", "BUILD_PAD"]
-
-PROBE_PAD = np.int32(2**31 - 1)
-BUILD_PAD = np.int32(2**31 - 2)
 
 # CPU default: jnp reference path (REPRO_USE_PALLAS=1 opts in to Pallas)
 _STATE = {"use_pallas": os.environ.get("REPRO_USE_PALLAS", "0") == "1"}
@@ -52,25 +49,37 @@ def _interpret() -> bool:
 
 
 def _pad_to(x: jax.Array, mult: int, fill) -> jax.Array:
-    n = x.shape[0]
-    rem = (-n) % mult
-    if rem == 0 and n > 0:
+    """Pad the last axis to a positive multiple of ``mult``."""
+    n = x.shape[-1]
+    rem = (-n) % mult if n else mult
+    if rem == 0:
         return x
-    return jnp.concatenate([x, jnp.full((max(rem, mult if n == 0 else rem),),
-                                        fill, x.dtype)])
+    pad = jnp.full(x.shape[:-1] + (rem,), fill, x.dtype)
+    return jnp.concatenate([x, pad], axis=-1)
 
 
 @functools.partial(jax.jit, static_argnames=("force_pallas",))
 def semijoin_mask(probe: jax.Array, build_sorted: jax.Array,
                   force_pallas: bool = False) -> jax.Array:
-    """mask[i] = probe[i] ∈ build_sorted (int32 0/1), any lengths ≥ 0."""
+    """mask[..., i] = probe[..., i] ∈ build_sorted[...] (int32 0/1), any
+    lengths ≥ 0.  A 2-D call is a batch of rows, each probe row against
+    its own build row, in one kernel launch."""
     if not (force_pallas or _STATE["use_pallas"]):
+        if probe.ndim == 2:
+            return jax.vmap(ref.semijoin_membership_ref)(probe, build_sorted)
         return ref.semijoin_membership_ref(probe, build_sorted)
-    n = probe.shape[0]
+    n = probe.shape[-1]
     a = _pad_to(probe.astype(jnp.int32), semijoin.TILE_A, PROBE_PAD)
     b = _pad_to(build_sorted.astype(jnp.int32), semijoin.TILE_B, BUILD_PAD)
-    out = semijoin.semijoin_membership_pallas(a, b, interpret=_interpret())
-    return out[:n]
+    bands = None
+    if a.ndim == 2:
+        # one flat sweep: each row's bands offset into the flat build
+        start, end = jax.vmap(tile_bands)(a, b)
+        off = (jnp.arange(a.shape[0], dtype=jnp.int32) * b.shape[1])[:, None]
+        bands = ((start + off).reshape(-1), (end + off).reshape(-1))
+    out = semijoin.semijoin_membership_pallas(
+        a.reshape(-1), b.reshape(-1), bands, interpret=_interpret())
+    return out.reshape(a.shape)[..., :n]
 
 
 @functools.partial(jax.jit, static_argnames=("force_pallas",))

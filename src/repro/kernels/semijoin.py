@@ -1,82 +1,59 @@
 """Pallas TPU kernel: sorted semi-join membership.
 
-The ExtVP builder and the on-the-fly semi-join reducer both need
-``mask[i] = probe[i] ∈ build`` over sorted int32 key columns.  On GPU one
-would hash-probe; on TPU the VPU (8×128 vector unit) makes *tiled
-broadcast-compare* the natural shape:
+The ExtVP load job needs ``mask[i] = probe[i] ∈ build`` over sorted int32
+key columns.  On GPU one would hash-probe; on TPU the VPU (8×128 vector
+unit) makes a broadcast-compare the natural shape: each grid step holds
+one ``(8, 128)`` probe tile in a vreg and compares it against every
+build key of its band (:mod:`repro.kernels.band`), each broadcast from
+SMEM, OR-accumulating the hits.
 
-  grid = (A_tiles, B_tiles); each program compares one probe tile
-  (TA keys, held in VMEM as an (8, TA/8)-packed block) against one build
-  tile (TB keys) with a (TA, TB) vectorized equality, reducing along TB
-  with a logical-any into the output block (revisited across the B grid
-  dimension — first iteration initializes, later ones OR-accumulate).
-
-Both sides are ascending, so a (min, max)-disjoint tile pair contributes
-nothing; the kernel still *loads* the block (BlockSpec pipelining is
-unconditional) but skips the O(TA·TB) compare via ``pl.when`` — on real
-hardware that removes ~all vector work for the off-diagonal of the grid,
-making effective cost O(A·TB + B·TA) instead of O(A·B).
-
-VMEM budget per program: TA·4 + TB·4 + TA·TB/8 (bool) bytes
-≈ 4 KiB + 2 KiB + 64 KiB for TA=1024, TB=512 — comfortably inside the
-~16 MiB/core VMEM of TPU v5e.
+A probe tile only meets the build keys inside its own ``[min, max]``
+range, so a sorted probe costs O(probe + build) vector compares, and an
+unsorted one at most O(probe · build / TILE).  VMEM holds one probe and
+one output block (4 KiB each, double-buffered); SMEM one ``CHUNK`` of
+build keys (8 KiB).
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.band import CHUNK, TILE, band_call, band_fold, tile_bands
+
 __all__ = ["semijoin_membership_kernel", "semijoin_membership_pallas",
            "TILE_A", "TILE_B"]
 
-TILE_A = 1024
-TILE_B = 512
+TILE_A = TILE        # probe keys per grid step
+TILE_B = CHUNK       # build-length multiple (one HBM -> SMEM copy)
 
 
-def semijoin_membership_kernel(a_ref, b_ref, out_ref):
-    """One (probe-tile, build-tile) cell of the sweep."""
-    j = pl.program_id(1)
+def semijoin_membership_kernel(start_ref, end_ref, a_ref, b_hbm, out_ref,
+                               buf, sem):
+    """One probe tile against its band of the build side."""
+    g = pl.program_id(0)
+    a = a_ref[...]                                  # (8, 128) int32
 
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+    def step(hit, b):
+        return hit | (a == b).astype(jnp.int32)
 
-    a = a_ref[...]            # (1, TA) int32 (any order)
-    b = b_ref[...]            # (1, TB) int32, ascending
-
-    a_lo, a_hi = jnp.min(a), jnp.max(a)   # probe tile need not be sorted
-    b_lo, b_hi = b[0, 0], b[0, -1]        # build side is globally ascending
-    overlap = jnp.logical_and(b_lo <= a_hi, a_lo <= b_hi)
-
-    @pl.when(overlap)
-    def _compare():
-        eq = a[0, :, None] == b[0, None, :]          # (TA, TB) VPU compare
-        hit = jnp.any(eq, axis=1).astype(jnp.int32)  # (TA,)
-        out_ref[...] = jnp.maximum(out_ref[...], hit[None, :])
+    out_ref[...] = band_fold(start_ref[g], end_ref[g], b_hbm, buf, sem,
+                             step, jnp.zeros(a.shape, jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def semijoin_membership_pallas(probe: jax.Array, build: jax.Array,
+                               bands: Optional[Tuple[jax.Array,
+                                                     jax.Array]] = None,
                                interpret: bool = True) -> jax.Array:
     """mask[i] = probe[i] ∈ build.  Build ascending; probe any order;
-    lengths multiples of the tile sizes (ops.py pads).  int32 in/out."""
-    n_a, n_b = probe.shape[0], build.shape[0]
-    assert n_a % TILE_A == 0 and n_b % TILE_B == 0, (n_a, n_b)
-    grid = (n_a // TILE_A, n_b // TILE_B)
-
-    return pl.pallas_call(
-        semijoin_membership_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, TILE_A), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, TILE_B), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, TILE_A), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_a // TILE_A, TILE_A), jnp.int32),
-        interpret=interpret,
-    )(probe.reshape(n_a // TILE_A, TILE_A),
-      build.reshape(n_b // TILE_B, TILE_B)).reshape(n_a)
+    lengths multiples of ``TILE_A`` / ``TILE_B`` (ops.py pads).  ``bands``
+    overrides the per-tile build ranges (:func:`tile_bands`); int32."""
+    start, end = bands if bands is not None else tile_bands(probe, build)
+    (mask,) = band_call(semijoin_membership_kernel, 1, probe, build,
+                        start, end, interpret)
+    return mask

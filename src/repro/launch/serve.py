@@ -108,6 +108,8 @@ def serve_lm(args) -> None:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", default="sparql", choices=["sparql", "lm"])
     ap.add_argument("--backend", default="distributed",
@@ -154,6 +156,7 @@ def main() -> None:
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--tokens", type=int, default=32)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mode == "sparql":
         serve_sparql(args)
     else:

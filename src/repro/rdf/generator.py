@@ -27,9 +27,11 @@ the generator stays O(N) vectorized:
      websites | cities | countries | genres | categories |
      integer literals 0..NUM_POOL | string-literal pool]
 
-Scale: ``scale_factor=1.0`` produces ~1.0e5 triples (WatDiv SF1); the
-paper's SF10000 would be ~1.09e9.  Everything is deterministic given
-``seed``.
+Scale: this generator makes ~2.97e4 triples per unit of
+``scale_factor`` (147,407 at 5; 10,105,965 at 340), so WatDiv's SF1
+(~1.1e5 triples) is ``scale_factor`` ~3.7 and the paper's SF100
+(~1.1e7) is ~340; its SF10000 would be ~1.09e9 triples.  Everything is
+deterministic given ``seed``.
 """
 
 from __future__ import annotations
@@ -309,8 +311,12 @@ def generate_watdiv(cfg: WatDivConfig) -> Tuple[np.ndarray, Dictionary, WatDivSc
         tt[pos:pos + k, 1] = pid
         tt[pos:pos + k, 2] = o
         pos += k
-    # deduplicate (multi-edges collapse, like real RDF sets)
-    tt = np.unique(tt, axis=0)
+    # deduplicate (multi-edges collapse, like real RDF sets): one int64
+    # key per row sorts like np.unique(tt, axis=0), far faster
+    span = tt.max(axis=0).astype(np.int64) + 1
+    key = np.unique((tt[:, 0] * span[1] + tt[:, 1]) * span[2] + tt[:, 2])
+    tt = np.stack([key // (span[1] * span[2]), key // span[2] % span[1],
+                   key % span[2]], axis=1).astype(np.int32)
     rng.shuffle(tt, axis=0)
 
     d = _build_dictionary(sch)

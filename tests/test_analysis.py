@@ -389,12 +389,12 @@ def test_lint_nonstatic_shape_from_traced_n():
 
 def test_lint_shard_map_check_rep():
     src = (
-        "from jax.experimental.shard_map import shard_map\n"
+        "from jax import shard_map\n"
         "def build(body, mesh, specs):\n"
         "    return shard_map(body, mesh=mesh, in_specs=specs,\n"
         "                     out_specs=specs)\n")
-    assert rules_of(lint_source(src)) == ["shard-map-check-rep"]
-    ok = src.replace("out_specs=specs)", "out_specs=specs, check_rep=False)")
+    assert rules_of(lint_source(src)) == ["shard-map-check-vma"]
+    ok = src.replace("out_specs=specs)", "out_specs=specs, check_vma=False)")
     assert lint_source(ok) == []
 
 

@@ -122,24 +122,24 @@ def test_per_pair_masks_match_ground_truth(crafted_vp):
     pidx = jnp.asarray([packed.slot[k[1]] for k in pairs], jnp.int32)
     bcol = jnp.asarray([eb.build_col(k[0]) for k in pairs], jnp.int32)
     bidx = jnp.asarray([packed.slot[k[2]] for k in pairs], jnp.int32)
-    runs = [eb.batch_pair_masks_bitmap(jnp.asarray(packed.keys),
+    uniq, mult = jnp.asarray(packed.uniq), jnp.asarray(packed.mult)
+    runs = [eb.batch_pair_masks_bitmap(uniq, mult,
                                        jnp.asarray(packed.present),
                                        pcol, pidx, bcol, bidx),
-            eb.batch_pair_masks(jnp.asarray(packed.keys),
-                                jnp.asarray(packed.uniq),
-                                pcol, pidx, bcol, bidx)]
-    for masks, counts in runs:
-        masks, counts = np.asarray(masks), np.asarray(counts)
+            eb.batch_pair_masks(uniq, mult, uniq, pcol, pidx, bcol, bidx)]
+    for member, counts in runs:
+        member, counts = np.asarray(member), np.asarray(counts)
         for j, (kind, p1, p2) in enumerate(pairs):
             t1, t2 = vp[p1], vp[p2]
             probe = t1.o if kind == OS else t1.s
             other = t2.unique_o if kind == SO else t2.unique_s
             want = _semijoin_mask(probe, other)
-            got = masks[j, :len(t1)].astype(bool)
+            got = packed.row_mask(kind, p1, member[j])
             assert np.array_equal(got, want), (kind, p1, p2)
             assert int(counts[j]) == int(want.sum())
-            # padded probe lanes never count
-            assert not masks[j, len(t1):].any()
+            # padded probe keys never match
+            n_keys = len(t1.unique_o if kind == OS else t1.unique_s)
+            assert not member[j, n_keys:].any()
 
 
 def test_sf_regimes_and_short_circuit(crafted_vp):

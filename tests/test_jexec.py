@@ -92,3 +92,32 @@ def test_device_join_random(data_strategy):
         for r in got[:, [cols.index(c) for c in ref.cols]])
     m2 = collections.Counter(map(tuple, ref.data.tolist()))
     assert m1 == m2
+
+
+@pytest.mark.parametrize("n", [1, 1000, 1024, 4096, 1 << 21])
+def test_prefix_sum_matches_cumsum(n):
+    """The two-level prefix sum (long inputs) is exactly cumsum."""
+    import jax.numpy as jnp
+    from repro.core.jexec import prefix_sum
+
+    x = np.random.default_rng(n).integers(0, 50, n).astype(np.int32)
+    np.testing.assert_array_equal(np.asarray(prefix_sum(jnp.asarray(x))),
+                                  np.cumsum(x))
+
+
+@pytest.mark.parametrize("out_cap", [16, 3000, 5000])
+def test_compact_is_stable_keep_first(out_cap):
+    """Sort-free compaction: kept rows in order at the front, PAD
+    behind, overflow exactly when they do not fit."""
+    import jax.numpy as jnp
+    from repro.core.jexec import _compact
+    from repro.rdf.dictionary import PAD
+
+    rng = np.random.default_rng(out_cap)
+    data = rng.integers(0, 99, (4096, 3)).astype(np.int32)
+    keep = rng.random(4096) < 0.7
+    got, n, ovf = _compact(jnp.asarray(data), jnp.asarray(keep), out_cap)
+    want = data[keep][:out_cap]
+    assert int(n) == len(want) and bool(ovf) == (keep.sum() > out_cap)
+    np.testing.assert_array_equal(np.asarray(got)[:len(want)], want)
+    assert (np.asarray(got)[len(want):] == PAD).all()
