@@ -45,7 +45,7 @@ from repro.core.jexec import (
     JBindings, bound_scan_seed, bounds_from_plan, build_key, check_spine,
     device_distinct, device_filter, device_join, device_left_join,
     device_order, device_project, device_resize, device_scan,
-    device_scan_tt, device_slice, device_union, double_caps,
+    device_scan_tt, device_slice, device_union, double_caps, count_retry,
     join_estimates, prefix_sum, prepare_value_keys, take_rows, _compact,
     _exec_cols, _mod_cap_seed, _step_meta, _tt_meta, _valid_mask,
 )
@@ -643,7 +643,10 @@ class DistributedExecutor:
     def run(self, max_retries: int = 16,
             bounds: Optional[np.ndarray] = None,
             fconsts: Optional[np.ndarray] = None,
-            trace=None) -> Tuple[np.ndarray, Tuple[str, ...]]:
+            trace=None, bind: Optional[int] = None
+            ) -> Tuple[np.ndarray, Tuple[str, ...]]:
+        """One binding over the mesh; ``bind`` and the ``device.fetch``
+        span as in :meth:`repro.core.jexec.PlanExecutor.run`."""
         flat = self._flat_inputs()
         b = self._default_bounds if bounds is None else \
             np.asarray(bounds, dtype=np.int32).reshape(self._default_bounds.shape)
@@ -651,6 +654,8 @@ class DistributedExecutor:
         fc = self.fconsts_from_mapping(None) if fconsts is None else \
             np.asarray(fconsts, dtype=np.int32).reshape(len(self.filter_slots))
         fj = jnp.asarray(fc)
+        if bind is not None:
+            trace.end(bind)
         caps = tuple(self.caps)
         for attempt in range(max_retries):
             if trace is not None:
@@ -670,29 +675,39 @@ class DistributedExecutor:
                                                     self._values, *flat)
                 ovf = np.asarray(ovf)
             if not ovf.any():
+                sid = trace.start("device.fetch") if trace is not None \
+                    else None
                 self.caps = list(caps)   # keep grown caps across requests
                 data = np.asarray(data)
                 ns = np.asarray(ns)
                 if self.gathered:        # replicated, already finalized
-                    return data[: int(ns[0])], self._final_cols()
-                rows = []
-                per = data.reshape(self.n_shards,
-                                   data.shape[0] // self.n_shards,
-                                   data.shape[-1])
-                for i in range(self.n_shards):
-                    rows.append(per[i][: int(ns[i])])
-                out = np.concatenate(rows, axis=0) if rows else np.empty((0, 0))
+                    out = data[: int(ns[0])]
+                else:
+                    rows = []
+                    per = data.reshape(self.n_shards,
+                                       data.shape[0] // self.n_shards,
+                                       data.shape[-1])
+                    for i in range(self.n_shards):
+                        rows.append(per[i][: int(ns[i])])
+                    out = np.concatenate(rows, axis=0) if rows \
+                        else np.empty((0, 0))
+                if trace is not None:
+                    trace.end(sid, bytes=data.nbytes + ns.nbytes,
+                              rows=len(out), retries=attempt)
                 return out, self._final_cols()
             caps = double_caps(caps, ovf, self._n_pipeline)
+            count_retry()
         raise RuntimeError("distributed join capacity overflow after retries")
 
     def run_batch(self, bounds_batch: Sequence[np.ndarray],
                   fconsts_batch: Optional[Sequence[np.ndarray]] = None,
-                  max_retries: int = 16,
-                  trace=None) -> List[Tuple[np.ndarray, Tuple[str, ...]]]:
+                  max_retries: int = 16, trace=None,
+                  bind: Optional[int] = None
+                  ) -> List[Tuple[np.ndarray, Tuple[str, ...]]]:
         """Execute B constant-bindings of the plan in one sharded launch;
         see :meth:`repro.core.jexec.PlanExecutor.run_batch` for the retry
-        contract (any element overflowing retries the whole batch)."""
+        contract (any element overflowing retries the whole batch) and
+        the spans."""
         if not bounds_batch:
             return []
         flat = self._flat_inputs()
@@ -707,6 +722,8 @@ class DistributedExecutor:
             fb = np.stack([np.asarray(f, dtype=np.int32).reshape(n_fc)
                            for f in fconsts_batch])
         fj = jnp.asarray(fb)
+        if bind is not None:
+            trace.end(bind)
         caps = tuple(self.caps)
         for attempt in range(max_retries):
             if trace is not None:
@@ -724,6 +741,8 @@ class DistributedExecutor:
                     caps, bj, fj, self._values, *flat)
                 ovf = np.asarray(ovf)            # (B, n_steps)
             if not ovf.any():
+                sid = trace.start("device.fetch") if trace is not None \
+                    else None
                 self.caps = list(caps)
                 data = np.asarray(data)          # (B, S*cap, k)
                 ns = np.asarray(ns)              # (B, S) or (B, 1)
@@ -741,8 +760,13 @@ class DistributedExecutor:
                     merged = np.concatenate(rows, axis=0) if rows \
                         else np.empty((0, 0))
                     out.append((merged, cols))
+                if trace is not None:
+                    trace.end(sid, bytes=data.nbytes + ns.nbytes,
+                              rows=sum(len(o[0]) for o in out),
+                              retries=attempt)
                 return out
             caps = double_caps(caps, ovf.any(axis=0), self._n_pipeline)
+            count_retry()
         raise RuntimeError(
             "distributed join capacity overflow after retries (batched)")
 

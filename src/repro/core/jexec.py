@@ -49,7 +49,7 @@ from repro.rdf.dictionary import PAD, UNBOUND
 __all__ = ["JBindings", "PlanExecutor", "device_join", "device_left_join",
            "device_union", "device_scan", "device_scan_tt",
            "device_scan_windowed", "build_key", "bounds_from_plan",
-           "trace_count", "device_filter", "device_project",
+           "trace_count", "retry_count", "device_filter", "device_project",
            "device_distinct", "device_order", "device_slice",
            "numeric_value_keys", "prepare_value_keys"]
 
@@ -204,6 +204,21 @@ def _valid_mask(cap: int, n: jax.Array) -> jax.Array:
     return jnp.arange(cap, dtype=jnp.int32) < n
 
 
+def _scoped(name: str):
+    """Trace the decorated device step under ``jax.named_scope(name)``,
+    so its operations carry the step's name in their HLO metadata (and a
+    profiler trace can tell one step's fusions from another's)."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+
+    return wrap
+
+
 _SCAN_ROW = 1024
 
 
@@ -252,6 +267,7 @@ def _compact(data: jax.Array, keep: jax.Array, out_cap: int,
     return gathered, jnp.minimum(n_keep, out_cap), n_keep > out_cap
 
 
+@_scoped("scan")
 def device_scan(rows: jax.Array, n: jax.Array, s_bound,
                 o_bound, same_var: bool,
                 out_cols: Sequence[int], out_cap: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -283,6 +299,16 @@ def build_key(b: JBindings, key_col: int) -> jax.Array:
     return jnp.where(_valid_mask(b.capacity, b.n), kb, B_SENT)
 
 
+def sort_build_key(b: JBindings, key_col: int
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """``(order_b, kb_sorted)``: the build side's key column sorted, the
+    ``b_presorted`` argument of :func:`device_join`."""
+    kb = build_key(b, key_col)
+    order_b = jnp.argsort(kb).astype(jnp.int32)
+    return order_b, kb[order_b]
+
+
+@_scoped("scan.window")
 def device_scan_windowed(rows: jax.Array, n: jax.Array, s_bound,
                          out_cols: Sequence[int],
                          out_cap: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -327,53 +353,55 @@ def _join_expand(a: JBindings, b: JBindings, out_cap: int,
 
     cap_a, cap_b = a.capacity, b.capacity
     if not shared:  # cross join (rare; bounded by caps)
-        ii = jnp.arange(out_cap, dtype=jnp.int32)
-        a_idx = jnp.clip(ii // jnp.maximum(b.n, 1), 0, cap_a - 1)
-        b_idx = ii % jnp.maximum(b.n, 1)
-        total = a.n * b.n
-        valid = ii < total
-        data = jnp.concatenate(
-            [take_rows(a.data, a_idx),
-             take_rows(b.data, jnp.clip(b_idx, 0, cap_b - 1))], axis=1)
+        with jax.named_scope("join.expand"):
+            ii = jnp.arange(out_cap, dtype=jnp.int32)
+            a_idx = jnp.clip(ii // jnp.maximum(b.n, 1), 0, cap_a - 1)
+            b_idx = ii % jnp.maximum(b.n, 1)
+            total = a.n * b.n
+            valid = ii < total
+            data = jnp.concatenate(
+                [take_rows(a.data, a_idx),
+                 take_rows(b.data, jnp.clip(b_idx, 0, cap_b - 1))], axis=1)
         return out_cols, data, a_idx, valid, total, False
 
-    ka = a.data[:, a.cols.index(shared[0])]
-    ka = jnp.where(ka == UNBOUND, A_NULL, ka)
-    ka = jnp.where(_valid_mask(cap_a, a.n), ka, A_SENT)
     if b_presorted is None:
-        kb = build_key(b, b.cols.index(shared[0]))
-        order_b = jnp.argsort(kb).astype(jnp.int32)
-        kb_sorted = kb[order_b]
-    else:
-        order_b, kb_sorted = b_presorted
-    lo = jnp.searchsorted(kb_sorted, ka, side="left").astype(jnp.int32)
-    hi = jnp.searchsorted(kb_sorted, ka, side="right").astype(jnp.int32)
-    cnt = hi - lo
-    prefix = prefix_sum(cnt) - cnt               # exclusive prefix
-    total = prefix[-1] + cnt[-1]
+        with jax.named_scope("join.build_sort"):
+            b_presorted = sort_build_key(b, b.cols.index(shared[0]))
+    order_b, kb_sorted = b_presorted
+    with jax.named_scope("join.probe"):
+        ka = a.data[:, a.cols.index(shared[0])]
+        ka = jnp.where(ka == UNBOUND, A_NULL, ka)
+        ka = jnp.where(_valid_mask(cap_a, a.n), ka, A_SENT)
+        lo = jnp.searchsorted(kb_sorted, ka, side="left").astype(jnp.int32)
+        hi = jnp.searchsorted(kb_sorted, ka, side="right").astype(jnp.int32)
+        cnt = hi - lo
+        prefix = prefix_sum(cnt) - cnt               # exclusive prefix
+        total = prefix[-1] + cnt[-1]
 
-    j = jnp.arange(out_cap, dtype=jnp.int32)
-    # rank search: which probe row produced output slot j
-    a_idx = jnp.searchsorted(prefix + cnt, j, side="right").astype(jnp.int32)
-    a_idx = jnp.clip(a_idx, 0, cap_a - 1)
-    off = j - prefix[a_idx]
-    b_pos = jnp.clip(lo[a_idx] + off, 0, cap_b - 1).astype(jnp.int32)
-    b_idx = order_b[b_pos]
-    valid = j < total
+    with jax.named_scope("join.expand"):
+        j = jnp.arange(out_cap, dtype=jnp.int32)
+        # rank search: which probe row produced output slot j
+        a_idx = jnp.searchsorted(prefix + cnt, j,
+                                 side="right").astype(jnp.int32)
+        a_idx = jnp.clip(a_idx, 0, cap_a - 1)
+        off = j - prefix[a_idx]
+        b_pos = jnp.clip(lo[a_idx] + off, 0, cap_b - 1).astype(jnp.int32)
+        b_idx = order_b[b_pos]
+        valid = j < total
 
-    left = take_rows(a.data, a_idx)
-    right = take_rows(b.data, b_idx)
+        left = take_rows(a.data, a_idx)
+        right = take_rows(b.data, b_idx)
 
-    # post-filter shared columns beyond the key (SQL NULL semantics)
-    for c in shared[1:]:
-        va = left[:, a.cols.index(c)]
-        vb = right[:, b.cols.index(c)]
-        valid &= (va == vb) & (va != UNBOUND)
+        # post-filter shared columns beyond the key (SQL NULL semantics)
+        for c in shared[1:]:
+            va = left[:, a.cols.index(c)]
+            vb = right[:, b.cols.index(c)]
+            valid &= (va == vb) & (va != UNBOUND)
 
-    pieces = [left]
-    if b_only:
-        pieces.append(right[:, [b.cols.index(c) for c in b_only]])
-    data = jnp.concatenate(pieces, axis=1)
+        pieces = [left]
+        if b_only:
+            pieces.append(right[:, [b.cols.index(c) for c in b_only]])
+        data = jnp.concatenate(pieces, axis=1)
     return out_cols, data, a_idx, valid, total, bool(shared[1:])
 
 
@@ -388,19 +416,22 @@ def device_join(a: JBindings, b: JBindings, out_cap: int,
     the bound constants."""
     out_cols, data, _, valid, total, needs_compact = _join_expand(
         a, b, out_cap, b_presorted)
-    if needs_compact:
-        data, n, ovf = _compact(data, valid, out_cap)
-    else:
-        # matches are contiguous at j < total (cross join, or the
-        # overwhelmingly common single-shared-variable star/chain case):
-        # masking replaces the O(out_cap log out_cap) compact sort
-        data = jnp.where(valid[:, None], data, PAD)
-        n = jnp.minimum(total, out_cap).astype(jnp.int32)
-        ovf = jnp.asarray(False)
+    with jax.named_scope("join.compact"):
+        if needs_compact:
+            data, n, ovf = _compact(data, valid, out_cap)
+        else:
+            # matches are contiguous at j < total (cross join, or the
+            # overwhelmingly common single-shared-variable star/chain
+            # case): masking replaces the O(out_cap log out_cap) compact
+            # sort
+            data = jnp.where(valid[:, None], data, PAD)
+            n = jnp.minimum(total, out_cap).astype(jnp.int32)
+            ovf = jnp.asarray(False)
     return JBindings(out_cols, data, n,
                      a.overflow | b.overflow | ovf | (total > out_cap))
 
 
+@_scoped("left_join")
 def device_left_join(a: JBindings, b: JBindings, out_cap: int,
                      expr: Optional[FilterExpr] = None,
                      values: Optional[jax.Array] = None,
@@ -441,6 +472,7 @@ def device_left_join(a: JBindings, b: JBindings, out_cap: int,
                      a.overflow | b.overflow | ovf | (total > out_cap))
 
 
+@_scoped("union")
 def device_union(a: JBindings, b: JBindings, out_cap: int) -> JBindings:
     """UNION: both operands lifted to the column union (UNBOUND fill),
     left rows first then right rows — the eager ``union`` sequence —
@@ -463,6 +495,7 @@ def device_union(a: JBindings, b: JBindings, out_cap: int) -> JBindings:
     return JBindings(cols, data, n, a.overflow | b.overflow | ovf)
 
 
+@_scoped("scan.tt")
 def device_scan_tt(rows: jax.Array, n: jax.Array, s_bound, p_bound, o_bound,
                    eqs: Sequence[Tuple[int, int]], take: Sequence[int],
                    out_cap: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -586,6 +619,7 @@ def _filter_mask(expr: FilterExpr, b: JBindings, values: jax.Array,
     return ((lid == rid) if expr.op == "=" else (lid != rid)) & ok
 
 
+@_scoped("filter")
 def device_filter(b: JBindings, expr: FilterExpr, values: jax.Array,
                   fconsts: jax.Array, ctr: List[int]) -> JBindings:
     """FILTER: mask + stable compact (kept rows stay in order)."""
@@ -595,6 +629,7 @@ def device_filter(b: JBindings, expr: FilterExpr, values: jax.Array,
     return JBindings(b.cols, data, n, b.overflow)
 
 
+@_scoped("spine.project")
 def device_project(b: JBindings, out_vars: Sequence[str]) -> JBindings:
     """Projection: gather the selected columns (UNBOUND-fill variables
     the pipeline does not produce), re-PAD invalid rows."""
@@ -608,6 +643,7 @@ def device_project(b: JBindings, out_vars: Sequence[str]) -> JBindings:
     return JBindings(tuple(out_vars), data, b.n, b.overflow)
 
 
+@_scoped("spine.resize")
 def device_resize(b: JBindings, out_cap: int
                   ) -> Tuple[JBindings, jax.Array]:
     """Re-buffer the relation to ``out_cap`` rows — a pure static
@@ -629,6 +665,7 @@ def device_resize(b: JBindings, out_cap: int
                      b.overflow), ovf
 
 
+@_scoped("spine.distinct")
 def device_distinct(b: JBindings) -> JBindings:
     """DISTINCT: lexsort + adjacent-unique to find duplicates, then a
     stable compact of the FIRST occurrence of each distinct row in the
@@ -652,6 +689,7 @@ def device_distinct(b: JBindings) -> JBindings:
     return JBindings(b.cols, data, n, b.overflow)
 
 
+@_scoped("spine.order")
 def device_order(b: JBindings, keys: Sequence[Tuple[str, bool]],
                  values: jax.Array) -> JBindings:
     """ORDER BY: stable lexsort over the dictionary's double-single
@@ -688,6 +726,7 @@ def device_order(b: JBindings, keys: Sequence[Tuple[str, bool]],
     return JBindings(b.cols, take_rows(b.data, order), b.n, b.overflow)
 
 
+@_scoped("spine.slice")
 def device_slice(b: JBindings, offset: int, limit: Optional[int]) -> JBindings:
     """OFFSET/LIMIT: static row-window over the compacted relation.  A
     LIMIT below the buffer capacity also *trims the buffer*, so only the
@@ -804,6 +843,24 @@ def trace_count() -> int:
     template workload should increase this once per (template, caps), not
     once per request — the observable for "no recompilation on re-bind"."""
     return _TRACE_COUNT
+
+
+_RETRY_COUNT = 0   # relaunches after an overflow, both executors
+
+
+def retry_count() -> int:
+    """Number of relaunches after a capacity overflow so far in this
+    process, by both device executors, single and batched, traced or
+    not.  Each one re-runs the whole program with doubled capacities
+    (and traces a new program the first time those capacities occur)."""
+    return _RETRY_COUNT
+
+
+def count_retry() -> None:
+    """One more relaunch after an overflow (the executors' retry
+    branch; see :func:`retry_count`)."""
+    global _RETRY_COUNT
+    _RETRY_COUNT += 1
 
 
 def bounds_from_plan(plan: Plan) -> np.ndarray:
@@ -1300,15 +1357,15 @@ class PlanExecutor:
                     key = next((c for c in acc_cols if c in cols), None)
                     pre = self._presorted(i, cur)
                     if pre is None and key is not None:
-                        kb = build_key(cur, cols.index(key))
-                        order_b = jnp.argsort(kb).astype(jnp.int32)
-                        pre = (order_b, kb[order_b])
+                        with jax.named_scope("join.build_sort"):
+                            pre = sort_build_key(cur, cols.index(key))
                     shared[i] = (cur, pre)
                 for c in cols:
                     if c not in acc_cols:
                         acc_cols.append(c)
 
-        hoist(self.core.root)
+        with jax.named_scope("shared"):
+            hoist(self.core.root)
 
         def one(b, fc):
             ctr = [0]
@@ -1352,7 +1409,13 @@ class PlanExecutor:
     def run(self, max_retries: int = 16,
             bounds: Optional[np.ndarray] = None,
             fconsts: Optional[np.ndarray] = None,
-            trace=None) -> Tuple[np.ndarray, Tuple[str, ...]]:
+            trace=None, bind: Optional[int] = None
+            ) -> Tuple[np.ndarray, Tuple[str, ...]]:
+        """One binding: the answer's rows and columns.  Traced, ``bind``
+        is the caller's open ``bind`` span, closed once the inputs are
+        on the device, and the copy back to the host is the
+        ``device.fetch`` span (``bytes`` copied, answer ``rows``, the
+        winning attempt's index as ``retries``)."""
         rows, ns, tt_rows, tt_n, values = self._device_inputs
         b = self._default_bounds if bounds is None else \
             np.asarray(bounds, dtype=np.int32).reshape(self._default_bounds.shape)
@@ -1360,6 +1423,8 @@ class PlanExecutor:
         fc = self.fconsts_from_mapping(None) if fconsts is None else \
             np.asarray(fconsts, dtype=np.int32).reshape(len(self.filter_slots))
         fj = jnp.asarray(fc)
+        if bind is not None:
+            trace.end(bind)
         caps = tuple(self.caps)
         for attempt in range(max_retries):
             if trace is not None:
@@ -1379,26 +1444,35 @@ class PlanExecutor:
                                             bj, fj, values)
                 ovf = np.asarray(ovf)
             if not ovf.any():
+                sid = trace.start("device.fetch") if trace is not None \
+                    else None
                 # keep grown caps: a hot template must not pay the
                 # overflow->retry double-launch on every request
                 self.caps = list(caps)
                 n = int(n)
                 cols = self._final_cols()
-                return np.asarray(data)[:n], cols
+                out = np.asarray(data)[:n]
+                if trace is not None:
+                    trace.end(sid, bytes=data.nbytes + 4, rows=n,
+                              retries=attempt)
+                return out, cols
             caps = double_caps(caps, ovf, self._n_pipeline)
+            count_retry()
         raise RuntimeError("join capacity overflow after retries")
 
     def run_batch(self, bounds_batch: Sequence[np.ndarray],
                   fconsts_batch: Optional[Sequence[np.ndarray]] = None,
-                  max_retries: int = 16,
-                  trace=None) -> List[Tuple[np.ndarray, Tuple[str, ...]]]:
+                  max_retries: int = 16, trace=None,
+                  bind: Optional[int] = None
+                  ) -> List[Tuple[np.ndarray, Tuple[str, ...]]]:
         """Execute B constant-bindings of this template's program in ONE
         XLA launch: the (B, n_steps, 2) bounds stack and the (B, n_fc)
         filter-constant stack are the only batched inputs (tables
         broadcast), so device work is amortized across the whole
         micro-batch.  Overflow on *any* batch element retries the whole
         batch with doubled caps — the batch shares one cap vector, which
-        keeps the program count at one per (caps, B)."""
+        keeps the program count at one per (caps, B).  ``bind`` and the
+        ``device.fetch`` span as in :meth:`run`."""
         if not bounds_batch:
             return []
         rows, ns, tt_rows, tt_n, values = self._device_inputs
@@ -1413,6 +1487,8 @@ class PlanExecutor:
             fb = np.stack([np.asarray(f, dtype=np.int32).reshape(n_fc)
                            for f in fconsts_batch])
         fj = jnp.asarray(fb)
+        if bind is not None:
+            trace.end(bind)
         caps = tuple(self.caps)
         for attempt in range(max_retries):
             if trace is not None:
@@ -1429,13 +1505,20 @@ class PlanExecutor:
                                                   tt_n, bj, fj, values)
                 ovf = np.asarray(ovf)            # (B, n_pipeline[+1])
             if not ovf.any():
+                sid = trace.start("device.fetch") if trace is not None \
+                    else None
                 self.caps = list(caps)
                 cols = self._final_cols()
                 data = np.asarray(data)
                 n = np.asarray(n)
-                return [(data[i, : int(n[i])], cols)
-                        for i in range(data.shape[0])]
+                out = [(data[i, : int(n[i])], cols)
+                       for i in range(data.shape[0])]
+                if trace is not None:
+                    trace.end(sid, bytes=data.nbytes + n.nbytes,
+                              rows=int(n.sum()), retries=attempt)
+                return out
             caps = double_caps(caps, ovf.any(axis=0), self._n_pipeline)
+            count_retry()
         raise RuntimeError("join capacity overflow after retries (batched)")
 
     def _final_cols(self) -> Tuple[str, ...]:

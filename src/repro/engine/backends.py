@@ -189,7 +189,13 @@ class _VectorizedPrepared(PreparedQuery):
     ``run`` feeds one bounds vector; ``run_batch`` stacks B of them into
     a leading batch axis and executes the whole micro-batch in a single
     launch.  Missing-constant bindings (S2RDF's statistics-only empty
-    answer) are answered on the host and never occupy a batch slot."""
+    answer) are answered on the host and never occupy a batch slot.
+
+    Traced, the host work before the launch — re-binding the plan,
+    building the bounds and filter-constant inputs and uploading them —
+    is the ``bind`` span, which the executor closes once the inputs are
+    on the device; it then adds ``device.launch`` and ``device.fetch``,
+    and ``run_batch`` ends with ``demux``."""
 
     vectorized_batch = True
 
@@ -213,11 +219,12 @@ class _VectorizedPrepared(PreparedQuery):
                 trace.event("short_circuit", why="constant missing "
                             "from the dictionary")
             return self._empty()
+        bind = trace.start("bind", batch=1) if trace is not None else None
         plan = rebind_plan(self.plan, binding.mapping)
         data, cols = self.executor.run(
             bounds=self.executor.bounds_from_plan(plan),
             fconsts=self.executor.fconsts_from_mapping(binding.mapping),
-            trace=trace)
+            trace=trace, bind=bind)
         if trace is None:
             return self._wrap(data, cols)
         sid = trace.start("decode")
@@ -229,6 +236,8 @@ class _VectorizedPrepared(PreparedQuery):
                   trace=None) -> List[Result]:
         bindings = [b or _NO_BINDING for b in bindings]
         results: List[Optional[Result]] = [None] * len(bindings)
+        bind = trace.start("bind", batch=len(bindings)) \
+            if trace is not None else None
         live: List[int] = []
         bounds: List[np.ndarray] = []
         fconsts: List[np.ndarray] = []
@@ -247,13 +256,16 @@ class _VectorizedPrepared(PreparedQuery):
             while len(bounds) < len(bindings):
                 bounds.append(bounds[-1])
                 fconsts.append(fconsts[-1])
-            outs = self.executor.run_batch(bounds, fconsts, trace=trace)
+            outs = self.executor.run_batch(bounds, fconsts, trace=trace,
+                                           bind=bind)
             sid = trace.start("demux", batch=len(bindings),
                               live=len(live)) if trace is not None else None
             for i, (data, cols) in zip(live, outs):
                 results[i] = self._wrap(data, cols)
             if trace is not None:
                 trace.end(sid)
+        elif trace is not None:
+            trace.end(bind)
         return results
 
     def lower(self, caps=None):

@@ -24,7 +24,7 @@ own runtimes are stable: here we cache *compilation*, never results.
 
 from __future__ import annotations
 
-from collections import OrderedDict, deque
+from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -42,11 +42,6 @@ from repro.runtime.config import runtime_config as _global_runtime_config
 
 __all__ = ["Engine", "ServerMetrics", "PlanCache"]
 
-
-# Compat sample windows (``latencies_ms`` / ``queue_ms`` below) keep only
-# the newest slice — the histograms are the real percentile source now
-# and never truncate.
-_MAX_SAMPLES = 8192
 
 # cardinality-drift reports cached per (prepared, binding): a hot
 # template's repeated traces must not re-run the host joins every time
@@ -82,40 +77,19 @@ class ServerMetrics:
     tracer: Optional[Tracer] = None
 
     def __post_init__(self) -> None:
-        # Histograms are the primary store: O(1) memory, O(1) record,
-        # exact counts, mergeable.  The bounded deques only back the
-        # legacy ``latencies_ms`` / ``queue_ms`` list views (compat shim
-        # until callers migrate) — a deque's maxlen trims in O(1) where
-        # the old lists materialized ``[ms] * count`` and re-sliced.
+        # Histograms are the store: O(1) memory, O(1) record, exact
+        # counts, mergeable.
         self.latency_hist = LogHistogram()
         self.queue_hist = LogHistogram()
-        self._lat_samples: "deque" = deque(maxlen=_MAX_SAMPLES)
-        self._queue_samples: "deque" = deque(maxlen=_MAX_SAMPLES)
-
-    # -- compat shims (deprecated list views; see docs/observability.md) ------
-    @property
-    def latencies_ms(self) -> List[float]:
-        """Newest latency samples as a list (bounded window).  Deprecated
-        read-only view — percentiles come from ``latency_hist`` now."""
-        return list(self._lat_samples)
-
-    @property
-    def queue_ms(self) -> List[float]:
-        """Newest queue-wait samples as a list (bounded window).
-        Deprecated read-only view — use ``queue_hist``."""
-        return list(self._queue_samples)
 
     def record_route(self, backend: str, count: int = 1) -> None:
         self.routed[backend] = self.routed.get(backend, 0) + count
 
     def record_latency(self, ms: float, count: int = 1) -> None:
         self.latency_hist.record(ms, count)
-        # the compat window never needs more than maxlen copies
-        self._lat_samples.extend([ms] * min(count, _MAX_SAMPLES))
 
     def record_queue(self, ms: float) -> None:
         self.queue_hist.record(ms)
-        self._queue_samples.append(ms)
 
     def runtime_report(self) -> Dict[str, object]:
         """The owning engine's router/tuner snapshot (empty when the

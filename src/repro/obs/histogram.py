@@ -2,7 +2,7 @@
 
 ``ServerMetrics`` used to keep bounded *sample lists* and compute
 percentiles with ``np.percentile`` — O(window) memory per metric, a
-truncation cliff at ``_MAX_SAMPLES``, and no way to merge two engines'
+truncation cliff at the window's length, and no way to merge two engines'
 metrics without concatenating raw samples.  :class:`LogHistogram` is the
 replacement: geometric (log-spaced) buckets with exact counts.
 

@@ -25,23 +25,82 @@ Finished traces flow into the tracer's
 reservoir) and feed per-stage :class:`~repro.obs.histogram.LogHistogram`
 aggregates, which :mod:`repro.obs.prometheus` exposes as
 ``repro_stage_ms`` series.
+
+Two things ride along with every span of an active tracer.  A
+:class:`GcMeter` hooked into ``gc.callbacks`` totals the garbage
+collector's pauses, and each span records the pauses that completed
+inside it (``gc_ms``, ``gc_n``).  And each span is mirrored as a
+``jax.profiler.TraceAnnotation`` named ``repro.<span name>``, so a
+profiler trace shows the program's spans on the same clock as the
+device's operations.
 """
 
 from __future__ import annotations
 
+import gc
+import weakref
 from typing import Any, Dict, List, Optional
 
 from repro.obs.histogram import LogHistogram
 from repro.obs.recorder import FlightRecorder
 
-__all__ = ["Span", "TraceContext", "Tracer"]
+__all__ = ["GcMeter", "Span", "TraceContext", "Tracer"]
+
+#: spans left off the profiler's timeline: a ``queue`` span opens in one
+#: call (``submit``) and closes in another, on another thread in a
+#: threaded server, where a profiler annotation cannot follow it
+UNMIRRORED = frozenset({"queue"})
+
+
+class GcMeter:
+    """Running total of the garbage collector's pauses, timed with the
+    config clock: a ``gc.callbacks`` hook, installed while a tracer is
+    active.  Only collections that complete are counted."""
+
+    __slots__ = ("clock", "ms", "n", "hooked", "_t0")
+
+    def __init__(self, clock):
+        self.clock = clock
+        self.ms = 0.0
+        self.n = 0
+        self.hooked = False
+        self._t0: Optional[float] = None
+
+    def __call__(self, phase: str, info: Dict[str, Any]) -> None:
+        if phase == "start":
+            self._t0 = self.clock()
+        elif self._t0 is not None:
+            self.ms += (self.clock() - self._t0) * 1e3
+            self.n += 1
+            self._t0 = None
+
+    def install(self) -> None:
+        if not self.hooked:
+            gc.callbacks.append(self)
+            self.hooked = True
+
+    def remove(self) -> None:
+        if self.hooked:
+            gc.callbacks.remove(self)
+            self.hooked = False
+            self._t0 = None
+
+
+def _annotation(name: str):
+    """An entered profiler annotation (cheap when no profile is taken)."""
+    from jax.profiler import TraceAnnotation
+
+    ann = TraceAnnotation(name)
+    ann.__enter__()
+    return ann
 
 
 class Span:
     """One timed region of a trace.  ``t0``/``t1`` are raw clock seconds
     (the config clock's units); ``t1 is None`` while the span is open."""
 
-    __slots__ = ("sid", "name", "parent", "t0", "t1", "attrs", "events")
+    __slots__ = ("sid", "name", "parent", "t0", "t1", "attrs", "events",
+                 "gc0", "ann")
 
     def __init__(self, sid: int, name: str, parent: Optional[int],
                  t0: float, attrs: Dict[str, Any]):
@@ -52,6 +111,8 @@ class Span:
         self.t1: Optional[float] = None
         self.attrs = attrs
         self.events: List[Dict[str, Any]] = []
+        self.gc0 = None           # the GcMeter's (ms, n) at the start
+        self.ann = None           # the open profiler annotation
 
     @property
     def duration_ms(self) -> Optional[float]:
@@ -71,18 +132,25 @@ class TraceContext:
     is carried *by argument* through the engine, batcher, prepared
     queries and executors — there is no thread-local or global state, so
     the untraced path never looks anything up.
+
+    A context begun by a :class:`Tracer` reads the tracer's
+    :class:`GcMeter` at each span's start and end, and mirrors each span
+    but the root (and the ``UNMIRRORED`` ones) as a profiler annotation.
     """
 
     __slots__ = ("trace_id", "clock", "spans", "_open", "_tracer",
-                 "duration_ms")
+                 "duration_ms", "_gc")
 
     def __init__(self, trace_id: int, clock, tracer: "Optional[Tracer]",
                  name: str = "request", **attrs: Any):
         self.trace_id = trace_id
         self.clock = clock
         self._tracer = tracer
+        self._gc: Optional[GcMeter] = None if tracer is None else tracer.gc
         self.duration_ms: Optional[float] = None
         root = Span(0, name, None, clock(), attrs)
+        if self._gc is not None:
+            root.gc0 = (self._gc.ms, self._gc.n)
         self.spans: List[Span] = [root]
         self._open: List[int] = [0]
 
@@ -96,9 +164,23 @@ class TraceContext:
         sid for :meth:`end`."""
         sid = len(self.spans)
         parent = self._open[-1] if self._open else 0
-        self.spans.append(Span(sid, name, parent, self.clock(), attrs))
+        span = Span(sid, name, parent, self.clock(), attrs)
+        if self._gc is not None:
+            span.gc0 = (self._gc.ms, self._gc.n)
+            if name not in UNMIRRORED:
+                span.ann = _annotation("repro." + name)
+        self.spans.append(span)
         self._open.append(sid)
         return sid
+
+    def _close(self, span: Span, t: float) -> None:
+        span.t1 = t
+        if span.gc0 is not None:
+            span.attrs["gc_ms"] = self._gc.ms - span.gc0[0]
+            span.attrs["gc_n"] = self._gc.n - span.gc0[1]
+        if span.ann is not None:
+            span.ann.__exit__(None, None, None)
+            span.ann = None
 
     def end(self, sid: int, **attrs: Any) -> None:
         """Close span ``sid`` (and anything left open inside it — a
@@ -107,12 +189,12 @@ class TraceContext:
         while self._open and self._open[-1] != sid:
             inner = self.spans[self._open.pop()]
             if inner.t1 is None:
-                inner.t1 = t
+                self._close(inner, t)
         if self._open and self._open[-1] == sid:
             self._open.pop()
         span = self.spans[sid]
         if span.t1 is None:
-            span.t1 = t
+            self._close(span, t)
         if attrs:
             span.attrs.update(attrs)
 
@@ -155,6 +237,10 @@ class Tracer:
     Reads ``trace_sample_rate`` from the config on every :meth:`begin`,
     so the rate is live-tunable (the overhead benchmark warms caches at
     rate 1.0 and then measures at the gated rates on the same engine).
+    The :class:`GcMeter` is hooked in by the first :meth:`begin` at a
+    rate above 0, and out again by the first :meth:`begin` or finished
+    trace that reads a rate of 0 (or when the tracer is collected); the
+    untraced path's :attr:`active` guard stays one compare.
     """
 
     def __init__(self, config):
@@ -171,6 +257,9 @@ class Tracer:
         self.sampled_out = 0      # requests the stride skipped
         self._seen = 0            # all begin() calls (stride counter)
         self._next_id = 0
+        #: garbage-collector pauses while tracing is on (see GcMeter)
+        self.gc = GcMeter(config.clock)
+        weakref.finalize(self, GcMeter.remove, self.gc)
 
     @property
     def active(self) -> bool:
@@ -184,7 +273,9 @@ class Tracer:
         samples it out (sampled-out requests create zero records)."""
         rate = self.config.trace_sample_rate
         if rate <= 0.0:
+            self.gc.remove()
             return None
+        self.gc.install()
         self._seen += 1
         if rate < 1.0:
             stride = max(1, round(1.0 / rate))
@@ -200,6 +291,8 @@ class Tracer:
 
     def _finished(self, ctx: TraceContext) -> None:
         self.finished += 1
+        if self.config.trace_sample_rate <= 0.0:
+            self.gc.remove()
         for span in ctx.spans:
             dur = span.duration_ms
             if dur is None:

@@ -195,7 +195,7 @@ def test_server_submit_flush_demux(ds):
         assert t.done() and t.result().same_as(oracle.query(q))
     m = srv.metrics.summary()
     assert m["batches"] == 1 and m["batched_requests"] == 5
-    assert len(srv.metrics.queue_ms) == 5
+    assert srv.metrics.queue_hist.count == 5
 
 
 def test_server_full_bucket_auto_flushes(ds):

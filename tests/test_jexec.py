@@ -121,3 +121,106 @@ def test_compact_is_stable_keep_first(out_cap):
     assert int(n) == len(want) and bool(ovf) == (keep.sum() > out_cap)
     np.testing.assert_array_equal(np.asarray(got)[:len(want)], want)
     assert (np.asarray(got)[len(want):] == PAD).all()
+
+
+# ---------------------------------------------------------------------------
+# Named device scopes and the overflow-retry counter
+# ---------------------------------------------------------------------------
+
+#: every named scope of the device program (core/jexec.py)
+SCOPES = ("scan", "scan.window", "scan.tt", "join.build_sort", "join.probe",
+          "join.expand", "join.compact", "left_join", "union", "filter",
+          "spine.resize", "spine.order", "spine.project", "spine.distinct",
+          "spine.slice", "shared")
+
+#: one template that reaches every device step
+SCOPED_QUERY = """SELECT DISTINCT ?u ?p WHERE {
+  ?u wsdbm:follows ?v . ?v wsdbm:likes ?p .
+  OPTIONAL { ?p rev:hasReview ?r }
+  { ?u wsdbm:friendOf ?f } UNION { ?u ?x wsdbm:User0 }
+  UNION { wsdbm:User0 wsdbm:follows ?u }
+  FILTER(?u != ?p) } ORDER BY ?p LIMIT 10"""
+
+
+def test_device_steps_carry_named_scopes(watdiv_small):
+    """Lowering one batched program names each device step in the HLO
+    metadata: scans, the join's build sort, probe, expand and compact,
+    left join, union, filter, the modifier spine and the hoisted shared
+    phase."""
+    import re
+
+    import jax.numpy as jnp
+
+    from repro.engine import Dataset
+
+    cat, d, sch = watdiv_small
+    eng = Dataset(catalog=cat, dictionary=d, schema=sch).engine("jit")
+    assert len(eng.query(SCOPED_QUERY)) > 0
+    ex = eng.prepare(SCOPED_QUERY).executor
+    rows, ns, tt_rows, tt_n, values = ex._device_inputs
+    bounds = jnp.asarray(np.stack([ex._default_bounds] * 2))
+    fconsts = jnp.asarray(np.stack([ex.fconsts_from_mapping(None)] * 2))
+    text = ex._jitted_batch.lower(tuple(ex.caps), rows, ns, tt_rows, tt_n,
+                                  bounds, fconsts, values) \
+        .as_text(debug_info=True)
+    scopes = {part for loc in re.findall(r'loc\("([^"]*)"', text)
+              for part in loc.split("/")}
+    assert set(SCOPES) <= scopes, sorted(set(SCOPES) - scopes)
+
+
+def _overflow_once(ex):
+    """Leave ``ex`` with capacities that overflow exactly once: grow every
+    slot from 16 to what the plan needs, then halve the last grown one
+    (its inputs fit, so it alone overflows, and one doubling fits)."""
+    ex.caps = [16 for _ in ex.caps]
+    ex.run()
+    need = list(ex.caps)
+    last = max(i for i, c in enumerate(need) if c > 16)
+    ex.caps[last] = need[last] // 2
+    return need
+
+
+@pytest.mark.parametrize("backend", ["jit", "distributed"])
+@pytest.mark.parametrize("batched", [False, True])
+def test_retry_count_counts_relaunches(watdiv_small, backend, batched):
+    """``retry_count()`` moves once per relaunch after an overflow, in
+    both executors, single and batched, traced or not; grown capacities
+    persist, so the next launch does not move it."""
+    import jax
+
+    from repro.core import jexec
+    from repro.core.distributed import DistributedExecutor
+    from repro.obs import TraceContext
+
+    cat, d, _ = watdiv_small
+    q = parse_sparql(
+        "SELECT * WHERE { ?u wsdbm:follows ?v . ?v wsdbm:likes ?p }", d)
+    plan = compile_bgp(q.root, cat)
+    ex = PlanExecutor(plan, cat) if backend == "jit" else \
+        DistributedExecutor(plan, cat, jax.make_mesh((1,), ("data",)))
+
+    def go(trace=None):
+        if batched:
+            return ex.run_batch([ex._default_bounds] * 2, trace=trace)[0]
+        return ex.run(trace=trace)
+
+    need = _overflow_once(ex)
+    before = jexec.retry_count()
+    data, _ = go()
+    assert jexec.retry_count() == before + 1
+    assert ex.caps == need
+    assert len(data) == len(execute(q, cat))
+    assert go()[0].shape == data.shape
+    assert jexec.retry_count() == before + 1
+
+    _overflow_once(ex)
+    trace = TraceContext(1, lambda: 0.0, None)
+    before = jexec.retry_count()
+    go(trace)
+    assert jexec.retry_count() == before + 1
+    launches = [s.attrs for s in trace.spans if s.name == "device.launch"]
+    assert [(a["attempt"], a["overflow"]) for a in launches] == \
+        [(0, True), (1, False)]
+    fetch = next(s.attrs for s in trace.spans if s.name == "device.fetch")
+    assert fetch["retries"] == 1
+    assert fetch["rows"] == len(data) * (2 if batched else 1)

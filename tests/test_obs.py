@@ -1,10 +1,12 @@
 """Observability layer (repro.obs): streaming histogram error bounds,
 deterministic stride sampling, span nesting under an injected fake
 clock, flight-recorder ring/slow-reservoir retention, Chrome trace
-export round-trips, ServerMetrics histogram migration (None percentiles
-on an idle server, O(1) trimming behind the compat list views), and the
-engine/batcher integration — device-launch spans carrying estimated AND
-actual per-step cardinalities on both device backends."""
+export round-trips, ServerMetrics histograms (None percentiles on an
+idle server, exact counts under a flood), the engine/batcher
+integration — device-launch spans carrying estimated AND actual
+per-step cardinalities on both device backends — and the spans between
+launches: ``bind``, ``device.fetch``, garbage-collector pauses
+(``gc_ms``/``gc_n``) and the ``repro.*`` profiler mirror."""
 
 import json
 
@@ -317,17 +319,16 @@ class TestServerMetrics:
         m.record_latency(5.0)
         m.record_latency(2.0, count=3)
         m.record_queue(1.5)
-        assert m.latencies_ms == [5.0, 2.0, 2.0, 2.0]
-        assert m.queue_ms == [1.5]
         assert m.latency_hist.count == 4
+        assert m.queue_hist.count == 1
+        assert m.summary()["queue_p50_ms"] == pytest.approx(1.5, rel=GROWTH)
         assert m.summary()["p50_ms"] == pytest.approx(2.0, rel=GROWTH)
 
     def test_list_views_trim_o1_under_flood(self):
-        from repro.engine.engine import _MAX_SAMPLES
         m = ServerMetrics()
-        m.record_latency(1.0, count=_MAX_SAMPLES * 3)
-        assert len(m.latencies_ms) == _MAX_SAMPLES    # bounded window
-        assert m.latency_hist.count == _MAX_SAMPLES * 3  # exact, untrimmed
+        m.record_latency(1.0, count=3 * 8192)
+        assert m.latency_hist.count == 3 * 8192          # exact, untrimmed
+        assert m.summary()["p99_ms"] == pytest.approx(1.0, rel=GROWTH)
 
     def test_prometheus_exposition(self):
         m = ServerMetrics()
@@ -442,3 +443,203 @@ class TestEngineTracing:
         assert "repro_served_total 1" in text
         assert 'repro_traces_total{state="finished"} 1' in text
         assert 'repro_stage_ms_bucket{stage="device.launch"' in text
+
+
+# ------------------------------------------- between launches: bind, fetch, gc
+
+class TickClock:
+    """A clock that moves 1 ms at every read."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        self.t += 0.001
+        return self.t
+
+
+def _children(ctx, sid):
+    return [s for s in ctx.spans if s.parent == sid]
+
+
+class CountingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records names."""
+
+    made: list = []
+    exited = 0
+
+    def __init__(self, name):
+        CountingAnnotation.made.append(name)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        CountingAnnotation.exited += 1
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", CountingAnnotation)
+    CountingAnnotation.made = []
+    CountingAnnotation.exited = 0
+    return CountingAnnotation
+
+
+class TestBetweenLaunches:
+    @pytest.mark.parametrize("backend", ["jit", "distributed"])
+    def test_bind_and_fetch_nest_under_execute_on_lead(self, ds, backend):
+        from repro.serve.batcher import MicroBatcher
+        kw = {"mesh": jax.make_mesh((1,), ("data",))} \
+            if backend == "distributed" else {}
+        eng = ds.engine(backend, runtime=RuntimeConfig(clock=TickClock()),
+                        **kw)
+        eng.query(QA)                 # grows the capacities untraced
+        eng.config.trace_sample_rate = 1.0
+        mb = MicroBatcher(eng, max_batch=8, flush_ms=1e9)
+        tickets = [mb.submit(QA) for _ in range(3)]
+        mb.flush()
+        answers = [len(t.result()) for t in tickets]
+        lead, *rest = [t.trace for t in tickets]
+        ex = next(s for s in lead.spans if s.name == "execute")
+        assert ex.attrs["shared_launch"] is False
+        kids = _children(lead, ex.sid)
+        assert [s.name for s in kids] == ["bind", "device.launch",
+                                          "device.fetch", "demux"]
+        bind, launch, fetch, demux = kids
+        shape = ex.attrs["shape"]
+        assert bind.attrs["batch"] == shape == launch.attrs["batch"] == 4
+        # the pad slot repeats the last binding: B answers come back
+        assert fetch.attrs["rows"] == sum(answers) + answers[-1]
+        assert fetch.attrs["retries"] == 0
+        assert fetch.attrs["bytes"] >= 4 * fetch.attrs["rows"] * 3
+        # one after another, all inside execute
+        assert ex.t0 < bind.t0 < bind.t1 <= launch.t0 < launch.t1 \
+            <= fetch.t0 < fetch.t1 <= demux.t0 < demux.t1 < ex.t1
+        for ctx in rest:
+            names = [s.name for s in ctx.spans]
+            assert "bind" not in names and "device.fetch" not in names
+
+    def test_single_query_binds_launches_fetches_decodes(self, ds):
+        eng = ds.engine("jit", runtime=RuntimeConfig(clock=TickClock()))
+        eng.query(QA)                 # grows the capacities untraced
+        eng.config.trace_sample_rate = 1.0
+        res = eng.query(QA)
+        ctx = eng.tracer.recorder.traces()[-1]
+        ex = next(s for s in ctx.spans if s.name == "execute")
+        kids = _children(ctx, ex.sid)
+        assert [s.name for s in kids] == ["bind", "device.launch",
+                                          "device.fetch", "decode"]
+        assert kids[0].attrs["batch"] == 1
+        assert kids[2].attrs["rows"] == len(res)
+
+    def test_device_launch_keeps_its_boundaries(self, ds):
+        """The fenced launch span holds the program call, the wait for
+        the device and the overflow flags' copy, and nothing after it;
+        ``device.fetch`` starts where it ends."""
+        ex = ds.engine("jit").prepare(QA).executor
+        clock = FakeClock()
+        k = len(ex._final_cols())
+
+        class Flags:
+            """Overflow flags whose copy to the host takes 1 ms."""
+
+            def __array__(self, dtype=None, copy=None):
+                clock.advance(0.001)
+                return np.zeros(len(ex.caps), bool)
+
+        def program(caps, *inputs):
+            clock.advance(0.005)
+            return np.zeros((8, k), np.int32), np.int32(3), Flags()
+
+        ex._jitted = program
+        ctx = TraceContext(1, clock, None)
+        data, _ = ex.run(trace=ctx)
+        ctx.finish()
+        (launch,) = [s for s in ctx.spans if s.name == "device.launch"]
+        (fetch,) = [s for s in ctx.spans if s.name == "device.fetch"]
+        assert launch.duration_ms == pytest.approx(6.0)
+        assert launch.attrs["cap_slots"] == sum(ex.caps)
+        assert fetch.t0 == launch.t1
+        assert fetch.attrs == {"bytes": 8 * k * 4 + 4, "rows": 3,
+                               "retries": 0}
+        assert len(data) == 3
+
+    def test_gc_pause_inside_span_is_counted(self):
+        import gc
+        tr = _tracer(clock=TickClock())
+        ctx = tr.begin("q")
+        gc.disable()
+        try:
+            quiet = ctx.start("plan")
+            ctx.end(quiet)
+            busy = ctx.start("execute")
+            gc.collect()
+            ctx.end(busy)
+        finally:
+            gc.enable()
+        ctx.finish()
+        span = ctx.spans[busy]
+        assert span.attrs["gc_n"] >= 1
+        # the tick clock reads once at a pause's start and once at its end
+        assert span.attrs["gc_ms"] == pytest.approx(span.attrs["gc_n"])
+        assert ctx.spans[quiet].attrs["gc_n"] == 0
+        assert ctx.spans[quiet].attrs["gc_ms"] == 0.0
+        assert ctx.root.attrs["gc_n"] == span.attrs["gc_n"]
+        tr.config.trace_sample_rate = 0.0
+        assert tr.begin("q") is None and tr.gc not in gc.callbacks
+
+    def test_gc_hook_follows_the_rate(self):
+        """Hooked in by the first traced request; out again with the
+        first trace that finishes, or the first begin, at rate 0."""
+        import gc
+        tr = _tracer()
+        assert tr.gc not in gc.callbacks
+        tr.begin("q").finish()
+        assert tr.gc in gc.callbacks
+        open_ctx = tr.begin("q")
+        tr.config.trace_sample_rate = 0.0
+        assert not tr.active and tr.gc in gc.callbacks
+        open_ctx.finish()
+        assert tr.gc not in gc.callbacks
+        tr.config.trace_sample_rate = 1.0
+        tr.begin("q")
+        assert tr.gc in gc.callbacks
+        tr.config.trace_sample_rate = 0.0
+        assert tr.begin("q") is None and tr.gc not in gc.callbacks
+
+    def test_rate_zero_installs_no_hook_and_no_annotation(self, ds,
+                                                          annotations):
+        import gc
+        from repro.serve.batcher import MicroBatcher
+        before = list(gc.callbacks)
+        eng = ds.engine("jit", runtime=RuntimeConfig())    # rate 0
+        eng.query(QA)
+        mb = MicroBatcher(eng, max_batch=8, flush_ms=1e9)
+        for _ in range(3):
+            mb.submit(QA)
+        mb.flush()
+        gc.collect()
+        assert gc.callbacks == before
+        assert annotations.made == []
+        assert eng.tracer.started == 0
+
+    def test_spans_mirror_to_profiler_annotations(self, ds, annotations):
+        from repro.serve.batcher import MicroBatcher
+        eng = ds.engine("jit",
+                        runtime=RuntimeConfig(trace_sample_rate=1.0))
+        mb = MicroBatcher(eng, max_batch=8, flush_ms=1e9)
+        tickets = [mb.submit(QA) for _ in range(3)]
+        mb.flush()
+        made = set(annotations.made)
+        for name in ("execute", "bind", "device.launch", "device.fetch",
+                     "demux"):
+            assert "repro." + name in made
+        # the root and the cross-call queue span stay off the timeline
+        assert "repro.request" not in made and "repro.queue" not in made
+        assert annotations.exited == len(annotations.made)
+        spans = [s for t in tickets for s in t.trace.spans
+                 if s.name not in ("request", "queue")]
+        assert len(annotations.made) == len(spans)
+        eng.config.trace_sample_rate = 0.0
+        assert eng.tracer.begin("q") is None
