@@ -7,12 +7,13 @@ the plan with doubled capacities (the standard static-buffer serving
 pattern).  Capacities are seeded from the catalog's ExtVP statistics — the
 same statistics the paper uses for join ordering — so overflows are rare.
 
-Join algorithm: sort-merge.  The probe side is key-sorted (XLA sort), the
-build side binary-searched (``jnp.searchsorted``), match counts expanded
-into output slots by a rank-search over the exclusive prefix sum.  All
-steps are O(n log n) vectorized primitives that map to TPU-friendly sort /
-gather / compare units — this is where the Pallas kernels of
-:mod:`repro.kernels` plug in for the probe phase.
+Join algorithm: sort-merge.  The build side is key-sorted (XLA sort, or
+stored in key order), the probe side binary-searched into it
+(:func:`_ranks`), match counts expanded into output slots by a
+rank-search over the exclusive prefix sum.  The rank searches and the
+gathers that consume them visit only a relation's live prefix, in chunks
+of :data:`CHUNK` rows (:func:`_by_chunks`): capacities are sized for the
+heaviest constants, and a request's live rows are mostly far fewer.
 
 Join keys are single int32 columns (the first shared variable); any
 further shared variables are post-filtered after expansion — BGP joins
@@ -25,7 +26,9 @@ values → per-side negative sentinels.
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -51,7 +54,7 @@ __all__ = ["JBindings", "PlanExecutor", "device_join", "device_left_join",
            "device_scan_windowed", "build_key", "bounds_from_plan",
            "trace_count", "retry_count", "device_filter", "device_project",
            "device_distinct", "device_order", "device_slice",
-           "numeric_value_keys", "prepare_value_keys"]
+           "numeric_value_keys", "prepare_value_keys", "CHUNK"]
 
 A_SENT = np.int32(2**31 - 1)   # probe-side padded-row key (== PAD)
 B_SENT = np.int32(2**31 - 2)   # build-side padded-row key (sort-max, != A_SENT)
@@ -246,6 +249,112 @@ def take_rows(data: jax.Array, idx: jax.Array) -> jax.Array:
     return jnp.stack([data[:, c][idx] for c in range(data.shape[1])], axis=1)
 
 
+_GATHER_1D = jax.lax.GatherDimensionNumbers(
+    offset_dims=(), collapsed_slice_dims=(0,), start_index_map=(0,))
+
+
+def _take(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """``x[idx]`` for a 1-D ``x`` and in-bounds indices, as one gather."""
+    return jax.lax.gather(
+        x, jax.lax.expand_dims(idx, (1,)), _GATHER_1D, (1,),
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
+@functools.partial(jax.jit, static_argnames="sides")
+def _ranks(sorted_x: jax.Array, q: jax.Array,
+           sides: Tuple[str, ...]) -> List[jax.Array]:
+    """``[jnp.searchsorted(sorted_x, q, side) for side in sides]`` for
+    int32 keys: binary searches of every query at once, one gather per
+    level and side, in one loop.  The passes of ``searchsorted``'s scan
+    in ``lax`` primitives: its vectorized scalar scan takes several times
+    longer to trace and lower, and a chunked search traces it in every
+    loop body of every program the server warms up."""
+    lax = jax.lax
+    n = sorted_x.shape[0]
+    if n == 0:
+        return [jnp.zeros(q.shape, jnp.int32) for _ in sides]
+    one, last = np.int32(1), np.int32(n - 1)
+
+    def level(c):
+        out = [lax.add(c[0], one)]
+        for side, lo, hi in zip(sides, c[1::2], c[2::2]):
+            mid = lax.shift_right_logical(lax.add(lo, hi), one)
+            x = _take(sorted_x, lax.min(mid, last))
+            below = lax.le(x, q) if side == "right" else lax.lt(x, q)
+            open_ = lax.lt(lo, hi)
+            out += [lax.select(lax.bitwise_and(open_, below),
+                               lax.add(mid, one), lo),
+                    lax.select(lax.bitwise_and(open_, lax.bitwise_not(below)),
+                               mid, hi)]
+        return tuple(out)
+
+    bounds = (lax.full(q.shape, 0, jnp.int32), lax.full(q.shape, n, jnp.int32))
+    levels = n.bit_length()
+    out = lax.while_loop(lambda c: lax.lt(c[0], np.int32(levels)), level,
+                         (np.int32(0),) + bounds * len(sides))
+    return list(out[1::2])
+
+
+#: query rows per chunk of a rank search (a multiple of the TPU's 8 x 128
+#: tile); a search over more slots than this visits the live ones only
+CHUNK = 2048
+
+_RANK = threading.local()
+
+
+@contextlib.contextmanager
+def _counting_rank_rows():
+    """Collect the rank searches traced inside the block: a list of
+    ``(rows searched, rows of the single-pass form)`` per search, the
+    first traced (int32), the second static."""
+    prev = getattr(_RANK, "log", None)
+    _RANK.log = log = []
+    try:
+        yield log
+    finally:
+        _RANK.log = prev
+
+
+def _by_chunks(rows_fn, live, size: int,
+               fills: Sequence) -> Tuple[jax.Array, ...]:
+    """The values of a per-slot search over slots ``[0, size)``, of which
+    only the first ``live`` (≤ ``size``) matter: ``rows_fn(j, at)``
+    returns a tuple of ``(len(j),)`` arrays for the slots ``j``, where
+    ``at(x)`` reads a ``(size,)`` array at those slots.
+
+    Up to :data:`CHUNK` slots it runs once over all of them.  Beyond, a
+    loop runs it on ``ceil(live / CHUNK)`` chunks of ``CHUNK`` slots and
+    writes each into buffers that hold ``fills`` elsewhere (the last
+    chunk of a size that is no multiple of ``CHUNK`` starts early and
+    rewrites the same values); ``rows_fn`` must give a slot at or past
+    ``live`` the value its fill gives.  So both forms give the same
+    arrays, and the work follows the live rows, not the capacity."""
+    log = getattr(_RANK, "log", None)
+    if size <= CHUNK:
+        if log is not None:
+            log.append((size, size))
+        return rows_fn(jnp.arange(size, dtype=jnp.int32), lambda x: x)
+    lax = jax.lax
+    trips = lax.div(lax.add(live, np.int32(CHUNK - 1)), np.int32(CHUNK))
+    offs = lax.iota(jnp.int32, CHUNK)
+
+    def body(c):
+        k, bufs = c
+        start = lax.min(lax.mul(k, np.int32(CHUNK)), np.int32(size - CHUNK))
+        vals = rows_fn(lax.add(start, offs),
+                       lambda x: lax.dynamic_slice(x, (start,), (CHUNK,)))
+        return lax.add(k, np.int32(1)), tuple(
+            lax.dynamic_update_slice(b, v, (start,))
+            for b, v in zip(bufs, vals))
+
+    bufs = tuple(lax.full((size,), f, jnp.asarray(f).dtype) for f in fills)
+    _, out = lax.while_loop(lambda c: lax.lt(c[0], trips), body,
+                            (np.int32(0), bufs))
+    if log is not None:
+        log.append((lax.mul(trips, np.int32(CHUNK)), size))
+    return out
+
+
 def _compact(data: jax.Array, keep: jax.Array, out_cap: int,
              fill: int = PAD) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Move keep-rows to the front (stable); returns (data, n, overflow).
@@ -253,18 +362,30 @@ def _compact(data: jax.Array, keep: jax.Array, out_cap: int,
     Output slot ``j`` takes the first row whose running keep-count
     exceeds ``j`` (a binary search over the prefix sum), so compaction
     needs no sort: a sort of a 10^5-row or larger buffer costs the TPU
-    compiler ~20 s per program."""
-    cap = data.shape[0]
+    compiler ~20 s per program.  Only the slots below the kept count
+    are searched (:func:`_by_chunks`)."""
+    cap, k = data.shape
     if cap == 0:
-        return (jnp.full((out_cap, data.shape[1]), fill, jnp.int32),
+        return (jnp.full((out_cap, k), fill, jnp.int32),
                 jnp.int32(0), jnp.asarray(False))
     running = prefix_sum(keep)
     n_keep = running[-1]
-    j = jnp.arange(out_cap, dtype=jnp.int32)
-    src = jnp.searchsorted(running, j, side="right").astype(jnp.int32)
-    gathered = take_rows(data, jnp.clip(src, 0, cap - 1))
-    gathered = jnp.where((j < n_keep)[:, None], gathered, fill)
-    return gathered, jnp.minimum(n_keep, out_cap), n_keep > out_cap
+    if k == 0:
+        return jnp.zeros((out_cap, 0), data.dtype), \
+            jnp.minimum(n_keep, out_cap), n_keep > out_cap
+    cols = [data[:, c] for c in range(k)]
+
+    def gather(j, at):
+        src = jax.lax.min(_ranks(running, j, ("right",))[0], cap - 1)
+        kept = j < n_keep
+        return tuple(jax.lax.select(kept, _take(col, src),
+                                    jnp.full(j.shape, fill, col.dtype))
+                     for col in cols)
+
+    out = _by_chunks(gather, jnp.minimum(n_keep, out_cap), out_cap,
+                     [fill] * k)
+    return jnp.stack(out, axis=1), jnp.minimum(n_keep, out_cap), \
+        n_keep > out_cap
 
 
 @_scoped("scan")
@@ -369,39 +490,45 @@ def _join_expand(a: JBindings, b: JBindings, out_cap: int,
             b_presorted = sort_build_key(b, b.cols.index(shared[0]))
     order_b, kb_sorted = b_presorted
     with jax.named_scope("join.probe"):
-        ka = a.data[:, a.cols.index(shared[0])]
-        ka = jnp.where(ka == UNBOUND, A_NULL, ka)
-        ka = jnp.where(_valid_mask(cap_a, a.n), ka, A_SENT)
-        lo = jnp.searchsorted(kb_sorted, ka, side="left").astype(jnp.int32)
-        hi = jnp.searchsorted(kb_sorted, ka, side="right").astype(jnp.int32)
-        cnt = hi - lo
+        ka_col = a.data[:, a.cols.index(shared[0])]
+
+        def probe(j, at):
+            ka = at(ka_col)
+            ka = jnp.where(ka == UNBOUND, A_NULL, ka)
+            ka = jnp.where(j < a.n, ka, A_SENT)
+            lo, hi = _ranks(kb_sorted, ka, ("left", "right"))
+            return lo, hi - lo
+
+        # a pad row's A_SENT sorts after every build key: lo = cap_b, cnt 0
+        lo, cnt = _by_chunks(probe, a.n, cap_a, [kb_sorted.shape[0], 0])
         prefix = prefix_sum(cnt) - cnt               # exclusive prefix
         total = prefix[-1] + cnt[-1]
 
     with jax.named_scope("join.expand"):
-        j = jnp.arange(out_cap, dtype=jnp.int32)
-        # rank search: which probe row produced output slot j
-        a_idx = jnp.searchsorted(prefix + cnt, j,
-                                 side="right").astype(jnp.int32)
-        a_idx = jnp.clip(a_idx, 0, cap_a - 1)
-        off = j - prefix[a_idx]
-        b_pos = jnp.clip(lo[a_idx] + off, 0, cap_b - 1).astype(jnp.int32)
-        b_idx = order_b[b_pos]
-        valid = j < total
+        incl = prefix + cnt
+        a_cols = [a.data[:, c] for c in range(len(a.cols))]
+        b_cols = {c: b.data[:, b.cols.index(c)] for c in shared[1:] + b_only}
 
-        left = take_rows(a.data, a_idx)
-        right = take_rows(b.data, b_idx)
+        def expand(j, at):
+            # rank search: which probe row produced output slot j
+            a_idx = jax.lax.min(_ranks(incl, j, ("right",))[0], cap_a - 1)
+            off = j - _take(prefix, a_idx)
+            b_pos = jnp.clip(_take(lo, a_idx) + off, 0, cap_b - 1)
+            b_idx = _take(order_b, b_pos)
+            valid = j < total
+            left = [_take(col, a_idx) for col in a_cols]
+            right = {c: _take(col, b_idx) for c, col in b_cols.items()}
+            # post-filter shared columns beyond the key (SQL NULL semantics)
+            for c in shared[1:]:
+                va = left[a.cols.index(c)]
+                valid &= (va == right[c]) & (va != UNBOUND)
+            return (a_idx, valid, *left, *(right[c] for c in b_only))
 
-        # post-filter shared columns beyond the key (SQL NULL semantics)
-        for c in shared[1:]:
-            va = left[:, a.cols.index(c)]
-            vb = right[:, b.cols.index(c)]
-            valid &= (va == vb) & (va != UNBOUND)
-
-        pieces = [left]
-        if b_only:
-            pieces.append(right[:, [b.cols.index(c) for c in b_only]])
-        data = jnp.concatenate(pieces, axis=1)
+        # a slot at or past total: the last probe row, invalid
+        a_idx, valid, *cols = _by_chunks(
+            expand, jnp.minimum(total, out_cap), out_cap,
+            [cap_a - 1, False] + [PAD] * len(out_cols))
+        data = jnp.stack(cols, axis=1)
     return out_cols, data, a_idx, valid, total, bool(shared[1:])
 
 
@@ -1091,6 +1218,9 @@ class PlanExecutor:
             pipe_cap = max(self.caps) if self.caps else 64
             self.caps.append(_mod_cap_seed(self.spine, pipe_cap))
         self._default_bounds = bounds_from_plan(self.plan)
+        #: (batched, caps) -> rows one binding's rank searches would
+        #: search in the single-pass form (filled when a program traces)
+        self._rank_cap_rows: Dict[Tuple[bool, Tuple[int, ...]], int] = {}
 
     def fconsts_from_mapping(self, mapping=None) -> np.ndarray:
         """Runtime filter-constant vector for one binding: template
@@ -1251,22 +1381,40 @@ class PlanExecutor:
         ovfs[ci] = out.overflow
         return JBindings(out.cols, out.data, out.n, no)
 
-    def _program(self, caps: Tuple[int, ...], table_rows: List[jax.Array],
+    def _binding(self, caps: Tuple[int, ...], table_rows: List[jax.Array],
                  table_ns: List[jax.Array], tt_rows: jax.Array,
                  tt_n: jax.Array, bounds: jax.Array, fconsts: jax.Array,
-                 values: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
-        global _TRACE_COUNT
-        _TRACE_COUNT += 1
+                 values: jax.Array, shared, batched: bool
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+        """One binding's evaluation: its answer buffer, row count,
+        per-step overflow flags and ``rank_rows``, the query rows its
+        rank searches searched.  The rows the single-pass form would
+        search, static, are kept per ``(batched, caps)`` for the traced
+        launch span."""
         ctr = [0]
         ovfs: List[jax.Array] = [jnp.asarray(False)] * self._n_pipeline
-        b = self._eval_seg(self.core.root, caps, table_rows, table_ns,
-                           tt_rows, tt_n, bounds, fconsts, values, ctr,
-                           ovfs, {})
-        b, mod_ovf = self._apply_spine(b, values, fconsts, caps, ctr)
+        with _counting_rank_rows() as ranks:
+            b = self._eval_seg(self.core.root, caps, table_rows, table_ns,
+                               tt_rows, tt_n, bounds, fconsts, values, ctr,
+                               ovfs, shared)
+            b, mod_ovf = self._apply_spine(b, values, fconsts, caps, ctr)
+        self._rank_cap_rows[(batched, caps)] = sum(c for _, c in ranks)
         stacked = jnp.stack(ovfs) if ovfs else jnp.zeros((0,), bool)
         if mod_ovf is not None:
             stacked = jnp.concatenate([stacked, mod_ovf[None]])
-        return b.data, b.n, stacked
+        rank = sum((jnp.asarray(r, jnp.int32) for r, _ in ranks),
+                   jnp.int32(0))
+        return b.data, b.n, stacked, rank
+
+    def _program(self, caps: Tuple[int, ...], table_rows: List[jax.Array],
+                 table_ns: List[jax.Array], tt_rows: jax.Array,
+                 tt_n: jax.Array, bounds: jax.Array, fconsts: jax.Array,
+                 values: jax.Array
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+        global _TRACE_COUNT
+        _TRACE_COUNT += 1
+        return self._binding(caps, table_rows, table_ns, tt_rows, tt_n,
+                             bounds, fconsts, values, {}, False)
 
     @functools.cached_property
     def _device_inputs(self) -> Tuple[List[jax.Array], List[jax.Array],
@@ -1303,7 +1451,8 @@ class PlanExecutor:
                          table_ns: List[jax.Array], tt_rows: jax.Array,
                          tt_n: jax.Array, bounds_b: jax.Array,
                          fconsts_b: jax.Array, values: jax.Array
-                         ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                         ) -> Tuple[jax.Array, jax.Array, jax.Array,
+                                    jax.Array]:
         """B constant-bindings of the template in one program.
 
         Constants only enter scan *selection values*, so any step whose
@@ -1368,20 +1517,13 @@ class PlanExecutor:
             hoist(self.core.root)
 
         def one(b, fc):
-            ctr = [0]
-            ovfs: List[jax.Array] = [jnp.asarray(False)] * self._n_pipeline
-            jb = self._eval_seg(self.core.root, caps, table_rows, table_ns,
-                                tt_rows, tt_n, b, fc, values, ctr, ovfs,
-                                shared)
-            jb, mod_ovf = self._apply_spine(jb, values, fc, caps, ctr)
-            stacked = jnp.stack(ovfs) if ovfs else jnp.zeros((0,), bool)
-            if mod_ovf is not None:
-                stacked = jnp.concatenate([stacked, mod_ovf[None]])
-            return jb.data, jb.n, stacked
+            return self._binding(caps, table_rows, table_ns, tt_rows, tt_n,
+                                 b, fc, values, shared, True)
 
         # one binding after another inside the launch: a vmapped batch
         # axis lands minor in the TPU layout of the join prefix sums and
-        # pads every (B, cap) buffer 128/B-fold
+        # pads every (B, cap) buffer 128/B-fold; each binding's rank
+        # searches take their own trip counts
         return jax.lax.map(lambda bf: one(*bf), (bounds_b, fconsts_b))
 
     @functools.cached_property
@@ -1434,14 +1576,15 @@ class PlanExecutor:
                 sid = trace.start("device.launch", backend="jit",
                                   attempt=attempt, batch=1,
                                   cap_slots=sum(caps))
-                data, n, ovf = self._jitted(caps, rows, ns, tt_rows,
-                                            tt_n, bj, fj, values)
-                jax.block_until_ready((data, n, ovf))
-                ovf = np.asarray(ovf)
-                trace.end(sid, overflow=bool(ovf.any()))
+                data, n, ovf, rank = self._jitted(caps, rows, ns, tt_rows,
+                                                  tt_n, bj, fj, values)
+                jax.block_until_ready((data, n, ovf, rank))
+                ovf, rank = jax.device_get((ovf, rank))
+                trace.end(sid, overflow=bool(ovf.any()),
+                          **self._rank_attrs(False, caps, rank))
             else:
-                data, n, ovf = self._jitted(caps, rows, ns, tt_rows, tt_n,
-                                            bj, fj, values)
+                data, n, ovf, _ = self._jitted(caps, rows, ns, tt_rows,
+                                               tt_n, bj, fj, values)
                 ovf = np.asarray(ovf)
             if not ovf.any():
                 sid = trace.start("device.fetch") if trace is not None \
@@ -1495,14 +1638,15 @@ class PlanExecutor:
                 sid = trace.start("device.launch", backend="jit",
                                   attempt=attempt, batch=len(bb),
                                   cap_slots=sum(caps))
-                data, n, ovf = self._jitted_batch(caps, rows, ns, tt_rows,
-                                                  tt_n, bj, fj, values)
-                jax.block_until_ready((data, n, ovf))
-                ovf = np.asarray(ovf)            # (B, n_pipeline[+1])
-                trace.end(sid, overflow=bool(ovf.any()))
+                data, n, ovf, rank = self._jitted_batch(
+                    caps, rows, ns, tt_rows, tt_n, bj, fj, values)
+                jax.block_until_ready((data, n, ovf, rank))
+                ovf, rank = jax.device_get((ovf, rank))
+                trace.end(sid, overflow=bool(ovf.any()),
+                          **self._rank_attrs(True, caps, rank))
             else:
-                data, n, ovf = self._jitted_batch(caps, rows, ns, tt_rows,
-                                                  tt_n, bj, fj, values)
+                data, n, ovf, _ = self._jitted_batch(caps, rows, ns, tt_rows,
+                                                     tt_n, bj, fj, values)
                 ovf = np.asarray(ovf)            # (B, n_pipeline[+1])
             if not ovf.any():
                 sid = trace.start("device.fetch") if trace is not None \
@@ -1520,6 +1664,15 @@ class PlanExecutor:
             caps = double_caps(caps, ovf.any(axis=0), self._n_pipeline)
             count_retry()
         raise RuntimeError("join capacity overflow after retries (batched)")
+
+    def _rank_attrs(self, batched: bool, caps: Tuple[int, ...],
+                    rank: np.ndarray) -> Dict[str, int]:
+        """The traced launch's ``rank_rows`` (query rows its rank
+        searches searched, over its bindings) and ``rank_cap_rows``
+        (what the single-pass form would search)."""
+        return {"rank_rows": int(np.sum(rank, dtype=np.int64)),
+                "rank_cap_rows": np.size(rank)
+                * self._rank_cap_rows[(batched, caps)]}
 
     def _final_cols(self) -> Tuple[str, ...]:
         return self._out_vars

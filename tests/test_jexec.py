@@ -6,10 +6,12 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.algebra import BGP, Query, TriplePattern
+from repro.core import jexec
+from repro.core.algebra import BGP, Cmp, Query, TriplePattern
 from repro.core.compiler import compile_bgp
 from repro.core.executor import execute
-from repro.core.jexec import PlanExecutor
+from repro.core.jexec import CHUNK, JBindings, PlanExecutor
+from repro.rdf.dictionary import PAD, UNBOUND
 from repro.core.sparql import parse_sparql
 from repro.core.stats import build_catalog
 
@@ -103,6 +105,26 @@ def test_prefix_sum_matches_cumsum(n):
     x = np.random.default_rng(n).integers(0, 50, n).astype(np.int32)
     np.testing.assert_array_equal(np.asarray(prefix_sum(jnp.asarray(x))),
                                   np.cumsum(x))
+
+
+@pytest.mark.parametrize("n", [0, 1, 2, 3, 1000, 4096])
+def test_ranks_match_searchsorted(n):
+    """The executor's binary search gives numpy's insertion points, both
+    sides, over duplicates, NULL and pad sentinels and keys outside the
+    array."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(n)
+    keys = np.sort(np.concatenate([
+        rng.integers(-6, 50, n - n // 8),
+        np.full(n // 8, jexec.B_SENT)])).astype(np.int32)
+    q = np.concatenate([rng.integers(-8, 60, 300),
+                        [jexec.A_SENT, jexec.B_SENT, jexec.A_NULL,
+                         jexec.B_NULL]]).astype(np.int32)
+    got = jexec._ranks(jnp.asarray(keys), jnp.asarray(q), ("left", "right"))
+    for side, g in zip(("left", "right"), got):
+        np.testing.assert_array_equal(np.asarray(g),
+                                      np.searchsorted(keys, q, side=side))
 
 
 @pytest.mark.parametrize("out_cap", [16, 3000, 5000])
@@ -224,3 +246,264 @@ def test_retry_count_counts_relaunches(watdiv_small, backend, batched):
     fetch = next(s.attrs for s in trace.spans if s.name == "device.fetch")
     assert fetch["retries"] == 1
     assert fetch["rows"] == len(data) * (2 if batched else 1)
+
+
+# ---------------------------------------------------------------------------
+# Rank searches over the live rows only, in chunks of CHUNK rows
+# ---------------------------------------------------------------------------
+
+#: capacity of the hand-built relations: four chunks
+CAP = 4 * CHUNK
+LIVE = (0, 1, CHUNK - 1, CHUNK, CHUNK + 1, CAP)
+
+
+def _rel(cols, rows, cap=CAP):
+    import jax.numpy as jnp
+
+    rows = np.asarray(rows, np.int32).reshape(-1, len(cols))
+    data = np.full((cap, len(cols)), PAD, np.int32)
+    data[:len(rows)] = rows
+    return JBindings(tuple(cols), jnp.asarray(data),
+                     jnp.asarray(len(rows), jnp.int32), jnp.asarray(False))
+
+
+def _np_expand(a, b):
+    """The natural join's output slots in order (probe-major, build rows
+    in their own order), each with whether the shared columns beyond the
+    key agree."""
+    (acols, arows), (bcols, brows) = a, b
+    shared = [c for c in acols if c in bcols]
+    b_only = [bcols.index(c) for c in bcols if c not in acols]
+    by_key = collections.defaultdict(list)
+    for r in brows:
+        by_key[r[bcols.index(shared[0])]].append(r)
+    slots = []
+    for r in arows:
+        for s in by_key.get(r[acols.index(shared[0])], []) \
+                if r[acols.index(shared[0])] != UNBOUND else []:
+            ok = all(r[acols.index(c)] == s[bcols.index(c)]
+                     and r[acols.index(c)] != UNBOUND for c in shared[1:])
+            slots.append((list(r) + [s[i] for i in b_only], ok))
+    return slots
+
+
+def _case(op, live):
+    """(device call, numpy rows, numpy overflow) of one operator whose
+    probe or input relation holds ``live`` rows."""
+    rng = np.random.default_rng(live + 7 * len(op))
+    x = rng.integers(0, CAP, live)
+    perm = rng.permutation(CAP)
+    if op == "join":            # one build row per key: `live` matches
+        a = (("x", "y"), np.stack([x, rng.integers(0, 99, live)], 1))
+        b = (("x", "z"), np.stack([perm, rng.integers(0, 99, CAP)], 1))
+        slots = _np_expand(a, b)
+        want = [r for r, _ in slots]
+        return ("join", a, b), want, False
+    if op == "left_join":       # odd keys have no build row
+        a = (("x", "y"), np.stack([x, rng.integers(0, 99, live)], 1))
+        even = perm[perm % 2 == 0]
+        b = (("x", "z"), np.stack([even, rng.integers(0, 99, len(even))], 1))
+        slots = _np_expand(a, b)
+        hit = {r[0] for r, _ in slots}
+        want = [r for r, _ in slots] + [
+            list(r) + [UNBOUND] for r in a[1] if r[0] not in hit]
+        return ("left_join", a, b), want, False
+    if op == "multi_key":       # two build rows per key, y agrees on one
+        y = rng.integers(0, 3, live)
+        y[rng.random(live) < 0.1] = UNBOUND
+        a = (("x", "y"), np.stack([x // 2, y], 1))
+        keys = np.repeat(perm[: CAP // 2], 2)
+        b = (("x", "y", "z"), np.stack(
+            [keys, (keys + np.arange(CAP) % 2) % 3,
+             rng.integers(0, 99, CAP)], 1))
+        slots = _np_expand(a, b)
+        want = [r for r, ok in slots[:CAP] if ok]
+        return ("join", a, b), want, len(slots) > CAP
+    if op == "filter":          # ?x != ?y
+        rows = np.stack([x, x % 5], 1)
+        want = [list(r) for r in rows if r[0] != r[1]]
+        return ("filter", (("x", "y"), rows)), want, False
+    assert op == "union"        # then 3 rows with a column of their own
+    rows = np.stack([x, x % 5], 1)
+    extra = np.arange(9).reshape(3, 3)
+    want = [list(r) + [UNBOUND] for r in rows] + \
+        [[r[0], UNBOUND, r[1]] for r in extra[:, :2]]
+    return ("union", (("x", "y"), rows), (("x", "z"), extra[:, :2])), \
+        want[:CAP], len(want) > CAP
+
+
+def _run(call):
+    """Trace and run ``call`` under jit with the current CHUNK; returns
+    (rows below n, n, overflow, the whole buffer) on the host."""
+    import jax
+    import jax.numpy as jnp
+
+    kind = call[0]
+    rels = [_rel(*r) for r in call[1:]]
+
+    def program(datas, ns):
+        rs = [JBindings(r.cols, d, n, jnp.asarray(False))
+              for r, d, n in zip(rels, datas, ns)]
+        if kind == "join":
+            out = jexec.device_join(rs[0], rs[1], CAP)
+        elif kind == "left_join":
+            out = jexec.device_left_join(rs[0], rs[1], CAP)
+        elif kind == "union":
+            out = jexec.device_union(rs[0], rs[1], CAP)
+        else:
+            out = jexec.device_filter(
+                rs[0], Cmp("!=", "x", "y"),
+                jnp.zeros((0, 4), jnp.float32),
+                jnp.zeros((0,), jnp.int32), [0])
+        return out.data, out.n, out.overflow
+
+    data, n, ovf = jax.jit(program)([r.data for r in rels],
+                                    [r.n for r in rels])
+    data = np.asarray(data)
+    return data[:int(n)], int(n), bool(ovf), data
+
+
+@pytest.mark.parametrize("live", LIVE)
+@pytest.mark.parametrize("op", ["join", "left_join", "multi_key", "filter",
+                                "union"])
+def test_chunked_equals_single_pass_and_numpy(monkeypatch, op, live):
+    """Join, left join, the multi-key join's compaction, filter and
+    union: searching the live rows in chunks gives the single-pass
+    buffers, n and overflow flag exactly, and numpy's rows in order
+    (truncated at the capacity where the flag is up)."""
+    call, want, want_ovf = _case(op, live)
+    chunked = _run(call)
+    monkeypatch.setattr(jexec, "CHUNK", 1 << 30)
+    single = _run(call)
+    np.testing.assert_array_equal(chunked[3], single[3])
+    assert chunked[1:3] == single[1:3]
+    assert chunked[2] == want_ovf
+    if not want_ovf:
+        np.testing.assert_array_equal(chunked[0], np.asarray(
+            want, np.int32).reshape(-1, chunked[3].shape[1]))
+    assert (chunked[3][chunked[1]:] == PAD).all()
+
+
+def test_join_past_out_cap(monkeypatch):
+    """total > out_cap: the expansion fills every slot, the overflow flag
+    is up in both forms, and the slots hold the first out_cap matches."""
+    rng = np.random.default_rng(3)
+    a = (("x",), rng.integers(0, CAP // 2, (CAP, 1)))
+    b = (("x", "z"), np.stack([np.arange(CAP) // 2,
+                               rng.integers(0, 99, CAP)], 1))
+    want = [r for r, _ in _np_expand(a, b)][:CAP]
+    chunked = _run(("join", a, b))
+    monkeypatch.setattr(jexec, "CHUNK", 1 << 30)
+    single = _run(("join", a, b))
+    np.testing.assert_array_equal(chunked[3], single[3])
+    assert chunked[1:3] == single[1:3] == (CAP, True)
+    np.testing.assert_array_equal(chunked[0], np.asarray(want, np.int32))
+
+
+#: a template whose bound subject gives each binding its own live rows
+BOUND_TEMPLATE = ("SELECT * WHERE { wsdbm:User%d wsdbm:follows ?v . "
+                  "?v wsdbm:likes ?p }")
+
+
+def _executor(cat, d, qtext):
+    return PlanExecutor(compile_bgp(parse_sparql(qtext, d).root, cat), cat)
+
+
+def _user_bounds(ex, d, users):
+    out = []
+    for u in users:
+        b = np.array(ex._default_bounds)
+        b[0, 0] = d.id_of(f"wsdbm:User{u}")
+        out.append(b)
+    return out
+
+
+def test_batched_bindings_with_different_live_counts(watdiv_small,
+                                                     monkeypatch):
+    """One batched launch whose bindings join different numbers of rows:
+    each binding's chunked searches take their own trip counts and give
+    the single-pass form's rows, in order, and the eager engine's bag."""
+    cat, d, _ = watdiv_small
+    users = [9, 0, 32, 1, 25]
+    qtext = BOUND_TEMPLATE % users[0]
+    monkeypatch.setattr(jexec, "CHUNK", 16)
+    chunked = _executor(cat, d, qtext)
+    chunked.caps = [64] * len(chunked.caps)
+    got = chunked.run_batch(_user_bounds(chunked, d, users))
+    monkeypatch.setattr(jexec, "CHUNK", 1 << 30)
+    single = _executor(cat, d, qtext)
+    single.caps = list(chunked.caps)
+    want = single.run_batch(_user_bounds(single, d, users))
+    assert len({len(r) for r, _ in got}) > 2
+    assert max(len(r) for r, _ in got) > 16
+    for u, (g, cols), (w, _) in zip(users, got, want):
+        np.testing.assert_array_equal(g, w)
+        ref = execute(parse_sparql(BOUND_TEMPLATE % u, d), cat)
+        assert collections.Counter(
+            tuple(int(x) for x in r)
+            for r in g[:, [cols.index(c) for c in ref.cols]]) == \
+            collections.Counter(map(tuple, ref.data.tolist()))
+
+
+def _searched(live, size, chunk):
+    return size if size <= chunk else -(-live // chunk) * chunk
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_traced_rank_rows_match_a_hand_count(watdiv_small, monkeypatch,
+                                             batched):
+    """The traced launch's ``rank_rows`` is Σ chunks × CHUNK over the
+    binding's rank searches, ``rank_cap_rows`` the single-pass rows: the
+    first scan's compaction, the probe and the expansion of the join,
+    and (unbatched only; the batched program hoists it) the second
+    scan's compaction at its table's capacity."""
+    from repro.obs import TraceContext
+
+    cat, d, _ = watdiv_small
+    chunk = 16
+    monkeypatch.setattr(jexec, "CHUNK", chunk)
+    q = parse_sparql(
+        "SELECT * WHERE { ?u wsdbm:follows ?v . ?v wsdbm:likes ?p }", d)
+    ex = PlanExecutor(compile_bgp(q.root, cat), cat)
+    b = 2 if batched else 1
+
+    def go(trace=None):
+        if batched:
+            return ex.run_batch([ex._default_bounds] * b, trace=trace)[0]
+        return ex.run(trace=trace)
+
+    data, _ = go()                         # grow the caps first
+    trace = TraceContext(1, lambda: 0.0, None)
+    go(trace)
+    (launch,) = [s.attrs for s in trace.spans if s.name == "device.launch"]
+    n0, n1 = (len(t) for t in ex.tables)
+    t1 = ex._device_inputs[0][1].shape[0]
+    c0, c1 = ex.caps
+    searches = [(min(n0, c0), c0), (min(n0, c0), c0),
+                (min(len(data), c1), c1)]
+    if not batched:
+        searches.append((n1, t1))
+    assert max(c0, c1) > chunk
+    assert launch["rank_rows"] == b * sum(
+        _searched(live, size, chunk) for live, size in searches)
+    assert launch["rank_cap_rows"] == b * sum(s for _, s in searches)
+    assert launch["rank_rows"] < launch["rank_cap_rows"]
+
+
+def test_one_program_per_caps_and_batch_shape(watdiv_small):
+    """Bindings with other live counts reuse the program of their
+    (caps, B): the trip counts are runtime values, not shapes."""
+    cat, d, _ = watdiv_small
+    users = [1, 2, 3, 4, 7, 11]
+    ex = _executor(cat, d, BOUND_TEMPLATE % 1)
+    ex.run_batch(_user_bounds(ex, d, users))         # grow the caps
+    t0 = jexec.trace_count()
+    ex.run_batch(_user_bounds(ex, d, [3, 4]))
+    ex.run_batch(_user_bounds(ex, d, [7, 11]))
+    assert jexec.trace_count() == t0 + 1
+    ex.run_batch(_user_bounds(ex, d, [1, 2, 3]))
+    ex.run_batch(_user_bounds(ex, d, [4, 7, 11]))
+    assert jexec.trace_count() == t0 + 2
+    for u in users:
+        ex.run(bounds=_user_bounds(ex, d, [u])[0])
+    assert jexec.trace_count() == t0 + 3

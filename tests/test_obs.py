@@ -550,9 +550,12 @@ class TestBetweenLaunches:
 
         def program(caps, *inputs):
             clock.advance(0.005)
-            return np.zeros((8, k), np.int32), np.int32(3), Flags()
+            return (np.zeros((8, k), np.int32), np.int32(3), Flags(),
+                    np.int32(40))
 
         ex._jitted = program
+        # what tracing the real program records for these caps
+        ex._rank_cap_rows[(False, tuple(ex.caps))] = 64
         ctx = TraceContext(1, clock, None)
         data, _ = ex.run(trace=ctx)
         ctx.finish()
@@ -560,6 +563,8 @@ class TestBetweenLaunches:
         (fetch,) = [s for s in ctx.spans if s.name == "device.fetch"]
         assert launch.duration_ms == pytest.approx(6.0)
         assert launch.attrs["cap_slots"] == sum(ex.caps)
+        assert (launch.attrs["rank_rows"],
+                launch.attrs["rank_cap_rows"]) == (40, 64)
         assert fetch.t0 == launch.t1
         assert fetch.attrs == {"bytes": 8 * k * 4 + 4, "rows": 3,
                                "retries": 0}
